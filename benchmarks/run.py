@@ -46,8 +46,503 @@ MODULES = {
 }
 
 
+def _child_env() -> dict:
+    """Environment of a smoke row's child process: this checkout's ``src``
+    on the path and the same persistent compilation cache."""
+    from repro import hardware
+
+    env = dict(os.environ)
+    env["PYTHONPATH"] = os.pathsep.join(
+        p for p in (env.get("PYTHONPATH"),
+                    os.path.join(os.path.dirname(
+                        os.path.dirname(os.path.abspath(__file__))), "src"))
+        if p)
+    env["JAX_COMPILATION_CACHE_DIR"] = hardware.use_compile_cache()
+    return env
+
+
 def smoke() -> int:
-    """Executor-parity + plan-cache smoke check.  Returns a process exit code."""
+    """Executor-parity + plan-cache smoke check.  Returns a process exit code.
+
+    The rows that run in child processes come first, while this process
+    has not touched JAX: on a TPU host the chip belongs to one process at a
+    time, so no child may need it while the parent holds it."""
+    failures: list[str] = []
+
+    # -- sharded handoff: the mesh executor streams in both directions -----
+    # A subprocess on a 2-device mesh: real devices where it sees two, else
+    # the same forced-host-device mesh CI's sharded tests use.  Gates:
+    # interior bytes exactly 0 on a 2-device mesh, NO gather event on the
+    # sharded→sharded boundary (the device-resident global array must pass
+    # through — an ``interior:gather`` in the event trail means an
+    # all-gather happened), the row actually exercised sharded streaming
+    # (passthrough > 0), and the warm run planned nothing and retraced
+    # nothing (the session-scoped trace counter).
+    import json as _json
+    import subprocess as _subprocess
+
+    _SHARDED_ROW = r'''
+import warnings; warnings.filterwarnings("ignore")
+import json, sys, time
+import numpy as np, jax, jax.numpy as jnp
+from repro.core import mozart
+from repro.core import annotated_numpy as anp
+
+handoff = sys.argv[1] == "on"
+n, b, evals = 400_000, 100_000, 3
+devices = jax.devices()
+if len(devices) < 2:
+    devices = jax.devices("cpu")         # the forced 2-device host platform
+mesh = jax.sharding.Mesh(np.array(devices[:2]), ("data",))
+x = jax.device_put(np.linspace(0.0, 1.0, n, dtype=np.float32), devices[0])
+
+def chain():
+    with mozart.session(executor="sharded", mesh=mesh, batch_elements=b,
+                        handoff=handoff) as ctx:
+        cur = x
+        for _ in range(evals):
+            cur = anp.multiply(anp.add(cur, 1.0), 0.5)
+            mozart.evaluate()            # sharded->sharded stage boundary
+        out = np.asarray(cur)
+    return out, ctx
+
+chain()                                  # plan (miss)
+chain()                                  # warm cache + pinned executables
+out, ctx = chain()                       # measured warm run (scoped view)
+samples = []
+for _ in range(5):
+    t0 = time.perf_counter(); chain(); samples.append(time.perf_counter() - t0)
+want = np.linspace(0.0, 1.0, n, dtype=np.float32)
+for _ in range(evals):
+    want = (want + 1.0) * 0.5
+print(json.dumps({
+    "parity": bool(np.allclose(out, want, rtol=2e-5)),
+    "devices": mesh.size,
+    "us": sorted(samples)[len(samples) // 2] * 1e6,
+    "interior": int(ctx.counters.bytes_interior()),
+    "terminal": int(ctx.counters.bytes_terminal()),
+    "events": ctx.counters.materialize_events(),
+    "traces": int(ctx.counters.trace_count()),
+    "planner_calls": int(ctx.stats.get("planner_calls", 0)),
+    "streamed": int(ctx.stats.get("streamed_outputs", 0)),
+    "passthrough": int(ctx.stats.get("shard_passthrough", 0)),
+    "ingests": int(ctx.stats.get("shard_ingests", 0)),
+    "converted": int(ctx.stats.get("stream_converted", 0)),
+    "donated": int(ctx.stats.get("donated_chunks", 0)),
+    "donation_copies": int(ctx.stats.get("donation_copies", 0)),
+    "rechunks": int(ctx.stats.get("handoff_rechunks", 0)),
+}))
+'''
+
+    def sharded_row(handoff: bool) -> dict | None:
+        # The forced count shapes only the host platform: the child meshes
+        # real devices when it sees two, else two host devices.
+        env = _child_env()
+        env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "") +
+                            " --xla_force_host_platform_device_count=2"
+                            ).strip()
+        proc = _subprocess.run(
+            [sys.executable, "-c", _SHARDED_ROW, "on" if handoff else "off"],
+            env=env, capture_output=True, text=True, timeout=900)
+        if proc.returncode != 0:
+            print(f"smoke/handoff/sharded subprocess failed:\n{proc.stderr}",
+                  file=sys.stderr)
+            return None
+        return _json.loads(proc.stdout.strip().splitlines()[-1])
+
+    on_row = sharded_row(True)
+    off_row = sharded_row(False)
+    sharded_failures = []
+    if on_row is None or off_row is None:
+        sharded_failures.append("subprocess")
+        record("smoke/handoff/sharded", 0.0, "SUBPROCESS_FAILED")
+    else:
+        if not (on_row["parity"] and off_row["parity"]):
+            sharded_failures.append("parity")
+        if on_row["devices"] < 2:
+            sharded_failures.append("single_device")
+        if on_row["interior"] != 0:
+            lines = [f"  - {kind[len('interior:'):]} at {where}: {nb} bytes"
+                     for kind, where, nb in on_row["events"]
+                     if kind.startswith("interior:")]
+            print("smoke/handoff/sharded: expected 0 interior boundary "
+                  f"bytes, got {on_row['interior']}:\n" + "\n".join(lines),
+                  file=sys.stderr)
+            sharded_failures.append(f"interior_bytes={on_row['interior']}")
+        # No all-gather on the sharded→sharded edge: asserted via the event
+        # trail, which names every gather the warm run performed.
+        gathers = [e for e in on_row["events"]
+                   if e[0].startswith("interior:gather")]
+        if gathers:
+            sharded_failures.append(f"all_gather={gathers}")
+        if on_row["streamed"] == 0 or on_row["passthrough"] == 0:
+            sharded_failures.append("no_streaming")
+        if on_row["planner_calls"] != 0:
+            sharded_failures.append("warm_planned")
+        if on_row["traces"] != 0:
+            sharded_failures.append("warm_retraced")
+        record("smoke/handoff/sharded", on_row["us"],
+               f"merge_path_us={off_row['us']:.0f};"
+               f"ratio={on_row['us'] / max(off_row['us'], 1e-9):.2f};"
+               f"interior={on_row['interior']};terminal={on_row['terminal']};"
+               f"off_interior={off_row['interior']};"
+               f"off_terminal={off_row['terminal']};"
+               f"streamed={on_row['streamed']};"
+               f"passthrough={on_row['passthrough']};"
+               f"ingests={on_row['ingests']};"
+               f"{'ok' if not sharded_failures else 'REGRESSED'}",
+               extra={
+                   "interior_bytes": int(on_row["interior"]),
+                   "terminal_bytes": int(on_row["terminal"]),
+                   "off_interior_bytes": int(off_row["interior"]),
+                   "off_terminal_bytes": int(off_row["terminal"]),
+                   "streamed_outputs": int(on_row["streamed"]),
+                   "stream_ingests": int(on_row["ingests"]),
+                   "stream_converted": int(on_row["converted"]),
+                   "donated_chunks": int(on_row["donated"]),
+                   "donation_copies": int(on_row["donation_copies"]),
+                   "handoff_rechunks": int(on_row["rechunks"]),
+                   "shard_passthrough": int(on_row["passthrough"]),
+               })
+    if sharded_failures:
+        failures.append(f"handoff/sharded:{sharded_failures}")
+
+    # -- serving: continuous batching matches fixed-group, stays warm ------
+    # Subprocess (fresh jax state, same pattern as the sharded row).  Gates:
+    # per-request token parity between the continuous-batching scheduler
+    # (mozart driver, right-pad + per-slot caches) and the fixed-group
+    # baseline (jit driver, left-pad + mask) under mixed prompt lengths and
+    # mixed max_new; zero planner calls and zero retraces across the warm
+    # run's occupancy churn.  p50/p99 latencies land in the JSON artifact.
+    _SERVING_ROW = r'''
+import warnings; warnings.filterwarnings("ignore")
+import json
+import numpy as np, jax
+from repro.configs.registry import get_smoke_config
+from repro.core.serving import ContinuousBatcher, ServeRequest
+from repro.launch.serve import Request, Server
+from repro.models import transformer as tfm
+
+cfg = get_smoke_config("internlm2-20b")
+params = tfm.init_model(jax.random.PRNGKey(0), cfg)
+rng = np.random.default_rng(0)
+specs = [(5, 3), (9, 7), (6, 2), (3, 5), (8, 4), (9, 1), (7, 6), (4, 2)]
+prompts = [rng.integers(0, cfg.vocab_size, p).astype(np.int32)
+           for p, _ in specs]
+max_len = 32
+
+def fixed_requests():
+    return [Request(rid=i, prompt=p, max_new=n)
+            for i, (p, (_, n)) in enumerate(zip(prompts, specs))]
+
+fixed = Server(cfg, params, batch=2, max_len=max_len, driver="jit",
+               mode="fixed")
+fixed.run(fixed_requests())                  # compile every group shape
+freqs = fixed_requests()
+fstats = fixed.run(freqs)
+
+def cont_requests():
+    return [ServeRequest(rid=i, prompt=p, max_new=n)
+            for i, (p, (_, n)) in enumerate(zip(prompts, specs))]
+
+b = ContinuousBatcher(cfg, params, batch=2, max_len=max_len, driver="mozart")
+b.warmup(max_prompt_len=9)
+b.run(cont_requests())                       # warm residual host paths
+creqs = cont_requests()
+cstats = b.run(creqs)
+
+print(json.dumps({
+    "parity": all(c.out == f.out for c, f in zip(creqs, freqs)),
+    "planner_calls": int(cstats["planner_calls"]),
+    "jit_traces": int(cstats["jit_traces"]),
+    "tokens": int(cstats["tokens"]),
+    "tokens_per_s": cstats["tokens_per_s"],
+    "fixed_tokens_per_s": fstats["tokens_per_s"],
+    "decode_p50_us": cstats["decode_p50_us"],
+    "decode_p99_us": cstats["decode_p99_us"],
+    "request_p50_ms": cstats["request_p50_ms"],
+    "request_p99_ms": cstats["request_p99_ms"],
+    "mean_occupancy": cstats["mean_occupancy"],
+    "us": cstats["wall_s"] * 1e6,
+}))
+'''
+
+    def serving_row() -> dict | None:
+        env = _child_env()
+        proc = _subprocess.run(
+            [sys.executable, "-c", _SERVING_ROW],
+            env=env, capture_output=True, text=True, timeout=900)
+        if proc.returncode != 0:
+            print(f"smoke/serving subprocess failed:\n{proc.stderr}",
+                  file=sys.stderr)
+            return None
+        return _json.loads(proc.stdout.strip().splitlines()[-1])
+
+    srow = serving_row()
+    serving_failures = []
+    if srow is None:
+        serving_failures.append("subprocess")
+        record("smoke/serving", 0.0, "SUBPROCESS_FAILED")
+    else:
+        if not srow["parity"]:
+            serving_failures.append("parity")
+        if srow["planner_calls"] != 0:
+            serving_failures.append("warm_planned")
+        if srow["jit_traces"] != 0:
+            serving_failures.append("warm_retraced")
+        ratio = srow["tokens_per_s"] / max(srow["fixed_tokens_per_s"], 1e-9)
+        record("smoke/serving", srow["us"],
+               f"tokens_per_s={srow['tokens_per_s']:.1f};"
+               f"fixed_tokens_per_s={srow['fixed_tokens_per_s']:.1f};"
+               f"ratio={ratio:.2f};"
+               f"decode_p50_us={srow['decode_p50_us']:.0f};"
+               f"decode_p99_us={srow['decode_p99_us']:.0f};"
+               f"occupancy={srow['mean_occupancy']:.2f};"
+               f"{'ok' if not serving_failures else 'REGRESSED'}",
+               extra={
+                   "tokens": int(srow["tokens"]),
+                   "tokens_per_s": srow["tokens_per_s"],
+                   "fixed_tokens_per_s": srow["fixed_tokens_per_s"],
+                   "ratio": ratio,
+                   "decode_p50_us": srow["decode_p50_us"],
+                   "decode_p99_us": srow["decode_p99_us"],
+                   "request_p50_ms": srow["request_p50_ms"],
+                   "request_p99_ms": srow["request_p99_ms"],
+                   "mean_occupancy": srow["mean_occupancy"],
+                   "planner_calls": int(srow["planner_calls"]),
+                   "jit_traces": int(srow["jit_traces"]),
+               })
+    if serving_failures:
+        failures.append(f"serving:{serving_failures}")
+
+    # -- sanitize: boundary sanitizer stays quiet on a clean handoff chain --
+    # Subprocess so MOZART_SANITIZE=1 is scoped to the row: a 3-stage
+    # handoff chain (exp -> add -> multiply -> sum) runs cold + warm on the
+    # fused executor with every MZ3xx boundary check armed (use-after-donate
+    # poisoning, stream-tiling validation, scoped-counter cross-checks).
+    # Gates: value parity vs numpy and zero SanitizerError violations.
+    _SANITIZE_ROW = r'''
+import warnings; warnings.filterwarnings("ignore")
+import json, time
+import numpy as np, jax.numpy as jnp
+from repro.core import mozart
+from repro.core import annotated_numpy as anp
+from repro.core.stage_exec import SanitizerError, sanitize_active
+
+n = 200_000
+x = jnp.linspace(0.1, 2.0, n, dtype=jnp.float32)
+y = jnp.linspace(0.2, 1.0, n, dtype=jnp.float32)
+
+def chain():
+    with mozart.session(executor="fused", handoff=True) as ctx:
+        a = anp.exp(x)
+        mozart.evaluate()                # stage boundary: streamed handoff
+        b = anp.add(a, y)
+        mozart.evaluate()                # second boundary (donated chunks)
+        c = anp.multiply(b, 0.5)
+        out = float(np.asarray(anp.sum(c)))
+    return out, ctx
+
+violations = []
+try:
+    chain()                              # cold: plan + sanitized run
+    t0 = time.perf_counter()
+    out, ctx = chain()                   # warm: sanitized handoff replay
+    us = (time.perf_counter() - t0) * 1e6
+except SanitizerError as e:
+    violations.append(str(e)); out, us, ctx = float("nan"), 0.0, None
+xs, ys = np.asarray(x), np.asarray(y)
+want = float(((np.exp(xs) + ys) * 0.5).sum())
+print(json.dumps({
+    "armed": bool(sanitize_active()),
+    "parity": bool(np.isfinite(out) and abs(out - want) <= 1e-2 * abs(want)),
+    "violations": violations,
+    "us": us,
+    "interior": int(ctx.counters.bytes_interior()) if ctx else -1,
+    "donated": int(ctx.stats.get("donated_chunks", 0)) if ctx else -1,
+}))
+'''
+
+    def sanitize_row() -> dict | None:
+        env = _child_env()
+        env["MOZART_SANITIZE"] = "1"
+        proc = _subprocess.run(
+            [sys.executable, "-c", _SANITIZE_ROW],
+            env=env, capture_output=True, text=True, timeout=900)
+        if proc.returncode != 0:
+            print(f"smoke/sanitize subprocess failed:\n{proc.stderr}",
+                  file=sys.stderr)
+            return None
+        return _json.loads(proc.stdout.strip().splitlines()[-1])
+
+    zrow = sanitize_row()
+    sanitize_failures = []
+    if zrow is None:
+        sanitize_failures.append("subprocess")
+        record("smoke/sanitize", 0.0, "SUBPROCESS_FAILED")
+    else:
+        if not zrow["armed"]:
+            sanitize_failures.append("not_armed")
+        if not zrow["parity"]:
+            sanitize_failures.append("parity")
+        if zrow["violations"]:
+            print("smoke/sanitize: boundary sanitizer tripped:\n" +
+                  "\n".join(f"  - {v}" for v in zrow["violations"]),
+                  file=sys.stderr)
+            sanitize_failures.append(f"violations={len(zrow['violations'])}")
+        record("smoke/sanitize", zrow["us"],
+               f"armed={zrow['armed']};violations={len(zrow['violations'])};"
+               f"interior={zrow['interior']};donated={zrow['donated']};"
+               f"{'ok' if not sanitize_failures else 'TRIPPED'}",
+               extra={
+                   "violations": zrow["violations"],
+                   "interior_bytes": int(zrow["interior"]),
+                   "donated_chunks": int(zrow["donated"]),
+               })
+    if sanitize_failures:
+        failures.append(f"sanitize:{sanitize_failures}")
+
+    # -- chaos: injected faults recover with exact results, nothing hangs ---
+    # Subprocess (fresh jax + fault-plan state).  Three scenarios from the
+    # resilience layer (core/resilience.py): an injected compile failure
+    # demotes down the executor ladder and quarantines the broken choice; an
+    # injected chunk OOM halves the batch (bounded) below the ladder; a
+    # serving step failure is routed into the in-flight requests while the
+    # driver thread survives to serve the next wave.  Gates: every fault run
+    # matches the fault-free baseline, retries stay bounded, recovery
+    # counters moved, and zero requests hang.
+    _CHAOS_ROW = r'''
+import warnings; warnings.filterwarnings("ignore")
+import json, time
+import numpy as np, jax, jax.numpy as jnp
+from repro.core import mozart, plan_cache, resilience
+from repro.core import annotated_numpy as anp
+
+n = 200_000
+x = jnp.linspace(0.1, 2.0, n, dtype=jnp.float32)
+y = jnp.linspace(0.2, 1.0, n, dtype=jnp.float32)
+
+def chain():
+    """3-stage handoff chain (exp -> add -> multiply -> sum)."""
+    with mozart.session(executor="fused", handoff=True) as ctx:
+        a = anp.exp(x)
+        mozart.evaluate()                # stage boundary: streamed handoff
+        b = anp.add(a, y)
+        mozart.evaluate()                # second boundary
+        c = anp.multiply(b, 0.5)
+        out = float(np.asarray(anp.sum(c)))
+    return out, ctx
+
+want, _ = chain()                        # fault-free baseline
+fails = []
+t0 = time.perf_counter()
+
+# 1) compile failure -> ladder demotion + quarantine, same answer
+plan_cache.clear()                       # force a fresh driver build
+with mozart.inject_faults("compile:fail:1") as p1:
+    got, ctx1 = chain()
+demotions = int(ctx1.stats.get("exec_demotions", 0))
+if not np.isclose(got, want, rtol=1e-5):
+    fails.append("compile_parity")
+if not p1.fired or demotions < 1:
+    fails.append("no_demotion")
+quarantined = sum(1 for e in plan_cache.entries() if e.quarantined)
+if quarantined < 1:
+    fails.append("no_quarantine")
+
+# 2) chunk OOM -> bounded batch halvings below the ladder, same answer
+plan_cache.clear()
+with mozart.inject_faults("chunk:oom:1") as p2:
+    got2, ctx2 = chain()
+halvings = int(ctx2.stats.get("chunk_oom_halvings", 0))
+if not np.isclose(got2, want, rtol=1e-5):
+    fails.append("oom_parity")
+if not p2.fired or not (1 <= halvings <= resilience.MAX_OOM_HALVINGS):
+    fails.append(f"halvings={halvings}")
+
+# 3) serving churn: a step fault fails in-flight requests VISIBLY, the
+#    driver survives, the next wave completes — zero hung requests
+from repro.configs.registry import get_smoke_config
+from repro.core.serving import AsyncServer, ContinuousBatcher
+from repro.models import transformer as tfm
+cfg = get_smoke_config("internlm2-20b")
+params = tfm.init_model(jax.random.PRNGKey(0), cfg)
+rng = np.random.default_rng(0)
+prompts = [rng.integers(0, cfg.vocab_size, p).astype(np.int32)
+           for p in (5, 7, 4, 6)]
+b = ContinuousBatcher(cfg, params, batch=2, max_len=32, driver="jit",
+                      max_queue=16)
+wave1 = [b.submit(b.make_request(p, 3)) for p in prompts[:2]]
+srv = AsyncServer(b, idle_poll_s=1e-4)
+with mozart.inject_faults("serve_step:fail:1"):
+    srv.start()
+    deadline = time.time() + 120
+    for r in wave1:
+        r.done.wait(max(0.0, deadline - time.time()))
+    wave2 = [b.submit(b.make_request(p, 4)) for p in prompts[2:]]
+    for r in wave2:
+        r.done.wait(max(0.0, deadline - time.time()))
+srv.close()
+hung = [r.rid for r in wave1 + wave2 if not r.finished]
+if hung:
+    fails.append(f"hung={hung}")
+if b.stats.get("step_failures", 0) != 1:
+    fails.append("driver_died_or_step_fault_missed")
+if not all(isinstance(r.error, resilience.InjectedFault) for r in wave1):
+    fails.append("fault_not_routed_to_requests")
+if not all(r.error is None and len(r.out) == 4 for r in wave2):
+    fails.append("post_fault_serving")
+
+print(json.dumps({
+    "fails": fails,
+    "us": (time.perf_counter() - t0) * 1e6,
+    "demotions": demotions,
+    "quarantined_entries": quarantined,
+    "oom_halvings": halvings,
+    "step_failures": int(b.stats.get("step_failures", 0)),
+    "failed_requests": int(b.stats.get("failed_requests", 0)),
+    "mz": {k: int(v) for k, v in resilience.stats.items()
+           if k.startswith("MZ")},
+}))
+'''
+
+    def chaos_row() -> dict | None:
+        env = _child_env()
+        env.pop("MOZART_FAULTS", None)   # the row arms its own plans
+        proc = _subprocess.run(
+            [sys.executable, "-c", _CHAOS_ROW],
+            env=env, capture_output=True, text=True, timeout=900)
+        if proc.returncode != 0:
+            print(f"smoke/chaos subprocess failed:\n{proc.stderr}",
+                  file=sys.stderr)
+            return None
+        return _json.loads(proc.stdout.strip().splitlines()[-1])
+
+    crow = chaos_row()
+    chaos_failures = []
+    if crow is None:
+        chaos_failures.append("subprocess")
+        record("smoke/chaos", 0.0, "SUBPROCESS_FAILED")
+    else:
+        chaos_failures.extend(crow["fails"])
+        record("smoke/chaos", crow["us"],
+               f"demotions={crow['demotions']};"
+               f"quarantined={crow['quarantined_entries']};"
+               f"oom_halvings={crow['oom_halvings']};"
+               f"step_failures={crow['step_failures']};"
+               f"{'ok' if not chaos_failures else 'REGRESSED'}",
+               extra={
+                   "demotions": int(crow["demotions"]),
+                   "quarantined_entries": int(crow["quarantined_entries"]),
+                   "oom_halvings": int(crow["oom_halvings"]),
+                   "step_failures": int(crow["step_failures"]),
+                   "failed_requests": int(crow["failed_requests"]),
+                   "mz_counters": crow["mz"],
+               })
+    if chaos_failures:
+        failures.append(f"chaos:{chaos_failures}")
+
+    # -- in-process rows: every child has exited ----------------------------
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -62,7 +557,6 @@ def smoke() -> int:
         call, put = w.black_scholes(**d)
         want = (np.asarray(call), np.asarray(put))
 
-    failures: list[str] = []
     for name in available_executors():
         kwargs = {}
         if name == "sharded":
@@ -245,498 +739,6 @@ def smoke() -> int:
         if handoff_failures:
             failures.append(f"handoff/{h_exec}:{handoff_failures}")
 
-    # -- sharded handoff: the mesh executor streams in both directions -----
-    # The parent process is single-device, so this row runs in a subprocess
-    # under the same forced-host-device mesh CI's sharded tests use.  Gates:
-    # interior bytes exactly 0 on a 2-device mesh, NO gather event on the
-    # sharded→sharded boundary (the device-resident global array must pass
-    # through — an ``interior:gather`` in the event trail means an
-    # all-gather happened), the row actually exercised sharded streaming
-    # (passthrough > 0), and the warm run planned nothing and retraced
-    # nothing (the session-scoped trace counter).
-    import json as _json
-    import subprocess as _subprocess
-
-    _SHARDED_ROW = r'''
-import warnings; warnings.filterwarnings("ignore")
-import json, sys, time
-import numpy as np, jax, jax.numpy as jnp
-from repro.core import mozart
-from repro.core import annotated_numpy as anp
-
-handoff = sys.argv[1] == "on"
-n, b, evals = 400_000, 100_000, 3
-mesh = jax.make_mesh((2,), ("data",))
-x = jnp.linspace(0.0, 1.0, n, dtype=jnp.float32)
-
-def chain():
-    with mozart.session(executor="sharded", mesh=mesh, batch_elements=b,
-                        handoff=handoff) as ctx:
-        cur = x
-        for _ in range(evals):
-            cur = anp.multiply(anp.add(cur, 1.0), 0.5)
-            mozart.evaluate()            # sharded->sharded stage boundary
-        out = np.asarray(cur)
-    return out, ctx
-
-chain()                                  # plan (miss)
-chain()                                  # warm cache + pinned executables
-out, ctx = chain()                       # measured warm run (scoped view)
-samples = []
-for _ in range(5):
-    t0 = time.perf_counter(); chain(); samples.append(time.perf_counter() - t0)
-want = np.linspace(0.0, 1.0, n, dtype=np.float32)
-for _ in range(evals):
-    want = (want + 1.0) * 0.5
-print(json.dumps({
-    "parity": bool(np.allclose(out, want, rtol=2e-5)),
-    "devices": jax.device_count(),
-    "us": sorted(samples)[len(samples) // 2] * 1e6,
-    "interior": int(ctx.counters.bytes_interior()),
-    "terminal": int(ctx.counters.bytes_terminal()),
-    "events": ctx.counters.materialize_events(),
-    "traces": int(ctx.counters.trace_count()),
-    "planner_calls": int(ctx.stats.get("planner_calls", 0)),
-    "streamed": int(ctx.stats.get("streamed_outputs", 0)),
-    "passthrough": int(ctx.stats.get("shard_passthrough", 0)),
-    "ingests": int(ctx.stats.get("shard_ingests", 0)),
-    "converted": int(ctx.stats.get("stream_converted", 0)),
-    "donated": int(ctx.stats.get("donated_chunks", 0)),
-    "donation_copies": int(ctx.stats.get("donation_copies", 0)),
-    "rechunks": int(ctx.stats.get("handoff_rechunks", 0)),
-}))
-'''
-
-    def sharded_row(handoff: bool) -> dict | None:
-        env = dict(os.environ)
-        # Run on the real mesh when the parent already sees one (GPU/TPU
-        # runner); otherwise force a 2-device host platform, same as CI's
-        # sharded tests.
-        if jax.device_count() < 2:
-            env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "") +
-                                " --xla_force_host_platform_device_count=2"
-                                ).strip()
-        env["PYTHONPATH"] = os.pathsep.join(
-            p for p in (env.get("PYTHONPATH"),
-                        os.path.join(os.path.dirname(
-                            os.path.dirname(os.path.abspath(__file__))), "src"))
-            if p)
-        proc = _subprocess.run(
-            [sys.executable, "-c", _SHARDED_ROW, "on" if handoff else "off"],
-            env=env, capture_output=True, text=True, timeout=900)
-        if proc.returncode != 0:
-            print(f"smoke/handoff/sharded subprocess failed:\n{proc.stderr}",
-                  file=sys.stderr)
-            return None
-        return _json.loads(proc.stdout.strip().splitlines()[-1])
-
-    on_row = sharded_row(True)
-    off_row = sharded_row(False)
-    sharded_failures = []
-    if on_row is None or off_row is None:
-        sharded_failures.append("subprocess")
-        record("smoke/handoff/sharded", 0.0, "SUBPROCESS_FAILED")
-    else:
-        if not (on_row["parity"] and off_row["parity"]):
-            sharded_failures.append("parity")
-        if on_row["devices"] < 2:
-            sharded_failures.append("single_device")
-        if on_row["interior"] != 0:
-            lines = [f"  - {kind[len('interior:'):]} at {where}: {nb} bytes"
-                     for kind, where, nb in on_row["events"]
-                     if kind.startswith("interior:")]
-            print("smoke/handoff/sharded: expected 0 interior boundary "
-                  f"bytes, got {on_row['interior']}:\n" + "\n".join(lines),
-                  file=sys.stderr)
-            sharded_failures.append(f"interior_bytes={on_row['interior']}")
-        # No all-gather on the sharded→sharded edge: asserted via the event
-        # trail, which names every gather the warm run performed.
-        gathers = [e for e in on_row["events"]
-                   if e[0].startswith("interior:gather")]
-        if gathers:
-            sharded_failures.append(f"all_gather={gathers}")
-        if on_row["streamed"] == 0 or on_row["passthrough"] == 0:
-            sharded_failures.append("no_streaming")
-        if on_row["planner_calls"] != 0:
-            sharded_failures.append("warm_planned")
-        if on_row["traces"] != 0:
-            sharded_failures.append("warm_retraced")
-        record("smoke/handoff/sharded", on_row["us"],
-               f"merge_path_us={off_row['us']:.0f};"
-               f"ratio={on_row['us'] / max(off_row['us'], 1e-9):.2f};"
-               f"interior={on_row['interior']};terminal={on_row['terminal']};"
-               f"off_interior={off_row['interior']};"
-               f"off_terminal={off_row['terminal']};"
-               f"streamed={on_row['streamed']};"
-               f"passthrough={on_row['passthrough']};"
-               f"ingests={on_row['ingests']};"
-               f"{'ok' if not sharded_failures else 'REGRESSED'}",
-               extra={
-                   "interior_bytes": int(on_row["interior"]),
-                   "terminal_bytes": int(on_row["terminal"]),
-                   "off_interior_bytes": int(off_row["interior"]),
-                   "off_terminal_bytes": int(off_row["terminal"]),
-                   "streamed_outputs": int(on_row["streamed"]),
-                   "stream_ingests": int(on_row["ingests"]),
-                   "stream_converted": int(on_row["converted"]),
-                   "donated_chunks": int(on_row["donated"]),
-                   "donation_copies": int(on_row["donation_copies"]),
-                   "handoff_rechunks": int(on_row["rechunks"]),
-                   "shard_passthrough": int(on_row["passthrough"]),
-               })
-    if sharded_failures:
-        failures.append(f"handoff/sharded:{sharded_failures}")
-
-    # -- serving: continuous batching matches fixed-group, stays warm ------
-    # Subprocess (fresh jax state, same pattern as the sharded row).  Gates:
-    # per-request token parity between the continuous-batching scheduler
-    # (mozart driver, right-pad + per-slot caches) and the fixed-group
-    # baseline (jit driver, left-pad + mask) under mixed prompt lengths and
-    # mixed max_new; zero planner calls and zero retraces across the warm
-    # run's occupancy churn.  p50/p99 latencies land in the JSON artifact.
-    _SERVING_ROW = r'''
-import warnings; warnings.filterwarnings("ignore")
-import json
-import numpy as np, jax
-from repro.configs.registry import get_smoke_config
-from repro.core.serving import ContinuousBatcher, ServeRequest
-from repro.launch.serve import Request, Server
-from repro.models import transformer as tfm
-
-cfg = get_smoke_config("internlm2-20b")
-params = tfm.init_model(jax.random.PRNGKey(0), cfg)
-rng = np.random.default_rng(0)
-specs = [(5, 3), (9, 7), (6, 2), (3, 5), (8, 4), (9, 1), (7, 6), (4, 2)]
-prompts = [rng.integers(0, cfg.vocab_size, p).astype(np.int32)
-           for p, _ in specs]
-max_len = 32
-
-def fixed_requests():
-    return [Request(rid=i, prompt=p, max_new=n)
-            for i, (p, (_, n)) in enumerate(zip(prompts, specs))]
-
-fixed = Server(cfg, params, batch=2, max_len=max_len, driver="jit",
-               mode="fixed")
-fixed.run(fixed_requests())                  # compile every group shape
-freqs = fixed_requests()
-fstats = fixed.run(freqs)
-
-def cont_requests():
-    return [ServeRequest(rid=i, prompt=p, max_new=n)
-            for i, (p, (_, n)) in enumerate(zip(prompts, specs))]
-
-b = ContinuousBatcher(cfg, params, batch=2, max_len=max_len, driver="mozart")
-b.warmup(max_prompt_len=9)
-b.run(cont_requests())                       # warm residual host paths
-creqs = cont_requests()
-cstats = b.run(creqs)
-
-print(json.dumps({
-    "parity": all(c.out == f.out for c, f in zip(creqs, freqs)),
-    "planner_calls": int(cstats["planner_calls"]),
-    "jit_traces": int(cstats["jit_traces"]),
-    "tokens": int(cstats["tokens"]),
-    "tokens_per_s": cstats["tokens_per_s"],
-    "fixed_tokens_per_s": fstats["tokens_per_s"],
-    "decode_p50_us": cstats["decode_p50_us"],
-    "decode_p99_us": cstats["decode_p99_us"],
-    "request_p50_ms": cstats["request_p50_ms"],
-    "request_p99_ms": cstats["request_p99_ms"],
-    "mean_occupancy": cstats["mean_occupancy"],
-    "us": cstats["wall_s"] * 1e6,
-}))
-'''
-
-    def serving_row() -> dict | None:
-        env = dict(os.environ)
-        env["PYTHONPATH"] = os.pathsep.join(
-            p for p in (env.get("PYTHONPATH"),
-                        os.path.join(os.path.dirname(
-                            os.path.dirname(os.path.abspath(__file__))), "src"))
-            if p)
-        proc = _subprocess.run(
-            [sys.executable, "-c", _SERVING_ROW],
-            env=env, capture_output=True, text=True, timeout=900)
-        if proc.returncode != 0:
-            print(f"smoke/serving subprocess failed:\n{proc.stderr}",
-                  file=sys.stderr)
-            return None
-        return _json.loads(proc.stdout.strip().splitlines()[-1])
-
-    srow = serving_row()
-    serving_failures = []
-    if srow is None:
-        serving_failures.append("subprocess")
-        record("smoke/serving", 0.0, "SUBPROCESS_FAILED")
-    else:
-        if not srow["parity"]:
-            serving_failures.append("parity")
-        if srow["planner_calls"] != 0:
-            serving_failures.append("warm_planned")
-        if srow["jit_traces"] != 0:
-            serving_failures.append("warm_retraced")
-        ratio = srow["tokens_per_s"] / max(srow["fixed_tokens_per_s"], 1e-9)
-        record("smoke/serving", srow["us"],
-               f"tokens_per_s={srow['tokens_per_s']:.1f};"
-               f"fixed_tokens_per_s={srow['fixed_tokens_per_s']:.1f};"
-               f"ratio={ratio:.2f};"
-               f"decode_p50_us={srow['decode_p50_us']:.0f};"
-               f"decode_p99_us={srow['decode_p99_us']:.0f};"
-               f"occupancy={srow['mean_occupancy']:.2f};"
-               f"{'ok' if not serving_failures else 'REGRESSED'}",
-               extra={
-                   "tokens": int(srow["tokens"]),
-                   "tokens_per_s": srow["tokens_per_s"],
-                   "fixed_tokens_per_s": srow["fixed_tokens_per_s"],
-                   "ratio": ratio,
-                   "decode_p50_us": srow["decode_p50_us"],
-                   "decode_p99_us": srow["decode_p99_us"],
-                   "request_p50_ms": srow["request_p50_ms"],
-                   "request_p99_ms": srow["request_p99_ms"],
-                   "mean_occupancy": srow["mean_occupancy"],
-                   "planner_calls": int(srow["planner_calls"]),
-                   "jit_traces": int(srow["jit_traces"]),
-               })
-    if serving_failures:
-        failures.append(f"serving:{serving_failures}")
-
-    # -- sanitize: boundary sanitizer stays quiet on a clean handoff chain --
-    # Subprocess so MOZART_SANITIZE=1 is scoped to the row: a 3-stage
-    # handoff chain (exp -> add -> multiply -> sum) runs cold + warm on the
-    # fused executor with every MZ3xx boundary check armed (use-after-donate
-    # poisoning, stream-tiling validation, scoped-counter cross-checks).
-    # Gates: value parity vs numpy and zero SanitizerError violations.
-    _SANITIZE_ROW = r'''
-import warnings; warnings.filterwarnings("ignore")
-import json, time
-import numpy as np, jax.numpy as jnp
-from repro.core import mozart
-from repro.core import annotated_numpy as anp
-from repro.core.stage_exec import SanitizerError, sanitize_active
-
-n = 200_000
-x = jnp.linspace(0.1, 2.0, n, dtype=jnp.float32)
-y = jnp.linspace(0.2, 1.0, n, dtype=jnp.float32)
-
-def chain():
-    with mozart.session(executor="fused", handoff=True) as ctx:
-        a = anp.exp(x)
-        mozart.evaluate()                # stage boundary: streamed handoff
-        b = anp.add(a, y)
-        mozart.evaluate()                # second boundary (donated chunks)
-        c = anp.multiply(b, 0.5)
-        out = float(np.asarray(anp.sum(c)))
-    return out, ctx
-
-violations = []
-try:
-    chain()                              # cold: plan + sanitized run
-    t0 = time.perf_counter()
-    out, ctx = chain()                   # warm: sanitized handoff replay
-    us = (time.perf_counter() - t0) * 1e6
-except SanitizerError as e:
-    violations.append(str(e)); out, us, ctx = float("nan"), 0.0, None
-xs, ys = np.asarray(x), np.asarray(y)
-want = float(((np.exp(xs) + ys) * 0.5).sum())
-print(json.dumps({
-    "armed": bool(sanitize_active()),
-    "parity": bool(np.isfinite(out) and abs(out - want) <= 1e-2 * abs(want)),
-    "violations": violations,
-    "us": us,
-    "interior": int(ctx.counters.bytes_interior()) if ctx else -1,
-    "donated": int(ctx.stats.get("donated_chunks", 0)) if ctx else -1,
-}))
-'''
-
-    def sanitize_row() -> dict | None:
-        env = dict(os.environ)
-        env["MOZART_SANITIZE"] = "1"
-        env["PYTHONPATH"] = os.pathsep.join(
-            p for p in (env.get("PYTHONPATH"),
-                        os.path.join(os.path.dirname(
-                            os.path.dirname(os.path.abspath(__file__))), "src"))
-            if p)
-        proc = _subprocess.run(
-            [sys.executable, "-c", _SANITIZE_ROW],
-            env=env, capture_output=True, text=True, timeout=900)
-        if proc.returncode != 0:
-            print(f"smoke/sanitize subprocess failed:\n{proc.stderr}",
-                  file=sys.stderr)
-            return None
-        return _json.loads(proc.stdout.strip().splitlines()[-1])
-
-    zrow = sanitize_row()
-    sanitize_failures = []
-    if zrow is None:
-        sanitize_failures.append("subprocess")
-        record("smoke/sanitize", 0.0, "SUBPROCESS_FAILED")
-    else:
-        if not zrow["armed"]:
-            sanitize_failures.append("not_armed")
-        if not zrow["parity"]:
-            sanitize_failures.append("parity")
-        if zrow["violations"]:
-            print("smoke/sanitize: boundary sanitizer tripped:\n" +
-                  "\n".join(f"  - {v}" for v in zrow["violations"]),
-                  file=sys.stderr)
-            sanitize_failures.append(f"violations={len(zrow['violations'])}")
-        record("smoke/sanitize", zrow["us"],
-               f"armed={zrow['armed']};violations={len(zrow['violations'])};"
-               f"interior={zrow['interior']};donated={zrow['donated']};"
-               f"{'ok' if not sanitize_failures else 'TRIPPED'}",
-               extra={
-                   "violations": zrow["violations"],
-                   "interior_bytes": int(zrow["interior"]),
-                   "donated_chunks": int(zrow["donated"]),
-               })
-    if sanitize_failures:
-        failures.append(f"sanitize:{sanitize_failures}")
-
-    # -- chaos: injected faults recover with exact results, nothing hangs ---
-    # Subprocess (fresh jax + fault-plan state).  Three scenarios from the
-    # resilience layer (core/resilience.py): an injected compile failure
-    # demotes down the executor ladder and quarantines the broken choice; an
-    # injected chunk OOM halves the batch (bounded) below the ladder; a
-    # serving step failure is routed into the in-flight requests while the
-    # driver thread survives to serve the next wave.  Gates: every fault run
-    # matches the fault-free baseline, retries stay bounded, recovery
-    # counters moved, and zero requests hang.
-    _CHAOS_ROW = r'''
-import warnings; warnings.filterwarnings("ignore")
-import json, time
-import numpy as np, jax, jax.numpy as jnp
-from repro.core import mozart, plan_cache, resilience
-from repro.core import annotated_numpy as anp
-
-n = 200_000
-x = jnp.linspace(0.1, 2.0, n, dtype=jnp.float32)
-y = jnp.linspace(0.2, 1.0, n, dtype=jnp.float32)
-
-def chain():
-    """3-stage handoff chain (exp -> add -> multiply -> sum)."""
-    with mozart.session(executor="fused", handoff=True) as ctx:
-        a = anp.exp(x)
-        mozart.evaluate()                # stage boundary: streamed handoff
-        b = anp.add(a, y)
-        mozart.evaluate()                # second boundary
-        c = anp.multiply(b, 0.5)
-        out = float(np.asarray(anp.sum(c)))
-    return out, ctx
-
-want, _ = chain()                        # fault-free baseline
-fails = []
-t0 = time.perf_counter()
-
-# 1) compile failure -> ladder demotion + quarantine, same answer
-plan_cache.clear()                       # force a fresh driver build
-with mozart.inject_faults("compile:fail:1") as p1:
-    got, ctx1 = chain()
-demotions = int(ctx1.stats.get("exec_demotions", 0))
-if not np.isclose(got, want, rtol=1e-5):
-    fails.append("compile_parity")
-if not p1.fired or demotions < 1:
-    fails.append("no_demotion")
-quarantined = sum(1 for e in plan_cache.entries() if e.quarantined)
-if quarantined < 1:
-    fails.append("no_quarantine")
-
-# 2) chunk OOM -> bounded batch halvings below the ladder, same answer
-plan_cache.clear()
-with mozart.inject_faults("chunk:oom:1") as p2:
-    got2, ctx2 = chain()
-halvings = int(ctx2.stats.get("chunk_oom_halvings", 0))
-if not np.isclose(got2, want, rtol=1e-5):
-    fails.append("oom_parity")
-if not p2.fired or not (1 <= halvings <= resilience.MAX_OOM_HALVINGS):
-    fails.append(f"halvings={halvings}")
-
-# 3) serving churn: a step fault fails in-flight requests VISIBLY, the
-#    driver survives, the next wave completes — zero hung requests
-from repro.configs.registry import get_smoke_config
-from repro.core.serving import AsyncServer, ContinuousBatcher
-from repro.models import transformer as tfm
-cfg = get_smoke_config("internlm2-20b")
-params = tfm.init_model(jax.random.PRNGKey(0), cfg)
-rng = np.random.default_rng(0)
-prompts = [rng.integers(0, cfg.vocab_size, p).astype(np.int32)
-           for p in (5, 7, 4, 6)]
-b = ContinuousBatcher(cfg, params, batch=2, max_len=32, driver="jit",
-                      max_queue=16)
-wave1 = [b.submit(b.make_request(p, 3)) for p in prompts[:2]]
-srv = AsyncServer(b, idle_poll_s=1e-4)
-with mozart.inject_faults("serve_step:fail:1"):
-    srv.start()
-    deadline = time.time() + 120
-    for r in wave1:
-        r.done.wait(max(0.0, deadline - time.time()))
-    wave2 = [b.submit(b.make_request(p, 4)) for p in prompts[2:]]
-    for r in wave2:
-        r.done.wait(max(0.0, deadline - time.time()))
-srv.close()
-hung = [r.rid for r in wave1 + wave2 if not r.finished]
-if hung:
-    fails.append(f"hung={hung}")
-if b.stats.get("step_failures", 0) != 1:
-    fails.append("driver_died_or_step_fault_missed")
-if not all(isinstance(r.error, resilience.InjectedFault) for r in wave1):
-    fails.append("fault_not_routed_to_requests")
-if not all(r.error is None and len(r.out) == 4 for r in wave2):
-    fails.append("post_fault_serving")
-
-print(json.dumps({
-    "fails": fails,
-    "us": (time.perf_counter() - t0) * 1e6,
-    "demotions": demotions,
-    "quarantined_entries": quarantined,
-    "oom_halvings": halvings,
-    "step_failures": int(b.stats.get("step_failures", 0)),
-    "failed_requests": int(b.stats.get("failed_requests", 0)),
-    "mz": {k: int(v) for k, v in resilience.stats.items()
-           if k.startswith("MZ")},
-}))
-'''
-
-    def chaos_row() -> dict | None:
-        env = dict(os.environ)
-        env.pop("MOZART_FAULTS", None)   # the row arms its own plans
-        env["PYTHONPATH"] = os.pathsep.join(
-            p for p in (env.get("PYTHONPATH"),
-                        os.path.join(os.path.dirname(
-                            os.path.dirname(os.path.abspath(__file__))), "src"))
-            if p)
-        proc = _subprocess.run(
-            [sys.executable, "-c", _CHAOS_ROW],
-            env=env, capture_output=True, text=True, timeout=900)
-        if proc.returncode != 0:
-            print(f"smoke/chaos subprocess failed:\n{proc.stderr}",
-                  file=sys.stderr)
-            return None
-        return _json.loads(proc.stdout.strip().splitlines()[-1])
-
-    crow = chaos_row()
-    chaos_failures = []
-    if crow is None:
-        chaos_failures.append("subprocess")
-        record("smoke/chaos", 0.0, "SUBPROCESS_FAILED")
-    else:
-        chaos_failures.extend(crow["fails"])
-        record("smoke/chaos", crow["us"],
-               f"demotions={crow['demotions']};"
-               f"quarantined={crow['quarantined_entries']};"
-               f"oom_halvings={crow['oom_halvings']};"
-               f"step_failures={crow['step_failures']};"
-               f"{'ok' if not chaos_failures else 'REGRESSED'}",
-               extra={
-                   "demotions": int(crow["demotions"]),
-                   "quarantined_entries": int(crow["quarantined_entries"]),
-                   "oom_halvings": int(crow["oom_halvings"]),
-                   "step_failures": int(crow["step_failures"]),
-                   "failed_requests": int(crow["failed_requests"]),
-                   "mz_counters": crow["mz"],
-               })
-    if chaos_failures:
-        failures.append(f"chaos:{chaos_failures}")
-
     # -- AOT pipeline: warm calls do ZERO planner calls and ZERO retraces ---
     plan_cache.clear()
     p = mozart.pipeline(lambda: w.black_scholes(**d), executor="auto")
@@ -866,6 +868,8 @@ def main() -> None:
                     help="comma-separated subset of " + ",".join(MODULES))
     args = ap.parse_args()
 
+    from repro import hardware
+    hardware.use_compile_cache()
     header()
     try:
         if args.smoke:

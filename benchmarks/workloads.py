@@ -6,6 +6,7 @@ from __future__ import annotations
 
 import math
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -37,19 +38,20 @@ def black_scholes(price, strike, t, rate, vol):
 
 
 def black_scholes_data(n, seed=0):
-    r = np.random.RandomState(seed)
+    """``n`` options made on the device from ``PRNGKey(seed)`` (traceable:
+    under ``jit`` with ``out_shardings`` it builds them already sharded)."""
+    kp, kk, kt, kv = jax.random.split(jax.random.PRNGKey(seed), 4)
+
+    def uniform(key, lo, hi):
+        return jax.random.uniform(key, (n,), jnp.float32, lo, hi)
+
     return dict(
-        price=jnp.asarray(r.uniform(10, 60, n), jnp.float32),
-        strike=jnp.asarray(r.uniform(10, 60, n), jnp.float32),
-        t=jnp.asarray(r.uniform(0.5, 2.0, n), jnp.float32),
-        rate=jnp.asarray(np.full(n, 0.02), jnp.float32),
-        vol=jnp.asarray(r.uniform(0.1, 0.6, n), jnp.float32),
+        price=uniform(kp, 10.0, 60.0),
+        strike=uniform(kk, 10.0, 60.0),
+        t=uniform(kt, 0.5, 2.0),
+        rate=jnp.full((n,), 0.02, jnp.float32),
+        vol=uniform(kv, 0.1, 0.6),
     )
-
-
-def black_scholes_ref(price, strike, t, rate, vol):
-    import scipy_less_erf as _  # noqa — no scipy; use math.erf via np
-    raise NotImplementedError
 
 
 def black_scholes_np(d):
@@ -188,7 +190,11 @@ def crime_index_np(table: tb.Table):
 
 def data_cleaning(table: tb.Table):
     """Fig 4e: replace broken values with NaN, then count valid per column."""
-    vals = tb.col(table, "value")
+    return clean_column(tb.col(table, "value"))
+
+
+def clean_column(vals):
+    """The body of ``data_cleaning`` on one column: (valid count, total)."""
     bad = anp.logical_or(anp.less(vals, 0.0), anp.greater(vals, 1e6))
     clean = anp.where(bad, jnp.float32(np.nan), vals)
     valid = anp.sum(anp.where(anp.isnan(clean), 0.0, 1.0))
@@ -196,8 +202,20 @@ def data_cleaning(table: tb.Table):
     return valid, total
 
 
+def data_cleaning_data(n, seed=0):
+    """A column of ``n`` values made on the device from ``PRNGKey(seed)``:
+    normal with scale 1e5, and about 5% set to -5 (broken readings)."""
+    kv, kb = jax.random.split(jax.random.PRNGKey(seed))
+    vals = jax.random.normal(kv, (n,), jnp.float32) * 1e5
+    return jnp.where(jax.random.uniform(kb, (n,)) < 0.05, -5.0, vals)
+
+
 def data_cleaning_np(table: tb.Table):
-    v = np.asarray(table.cols["value"], np.float64)
+    return clean_column_np(table.cols["value"])
+
+
+def clean_column_np(vals):
+    v = np.asarray(vals, np.float64)
     bad = (v < 0) | (v > 1e6)
     c = np.where(bad, np.nan, v)
     return float((~np.isnan(c)).sum()), float(np.nansum(c))

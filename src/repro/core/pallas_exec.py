@@ -1,21 +1,30 @@
 """Lower a planned Mozart stage onto the split-pipeline Pallas kernel.
 
-Eligibility (checked, with graceful fallback to the fused executor):
+Eligibility (checked, with fallback to the fused executor):
   * every node is annotated ``elementwise=True``, or is a whole-array
     reduction whose output type is ``ReduceSplit`` (sum/max/min/prod);
   * every splittable stage input is a 1-D ``ArraySplit`` along axis 0 and
     all agree on length;
   * broadcast inputs are scalars ();
-  * reductions are only consumed outside the stage (they produce partials).
+  * reductions are only consumed outside the stage (they produce partials);
+  * the chain uses only primitives the kernel can lower
+    (``split_pipeline.LOWERABLE_PRIMITIVES``).  A chain that fails this
+    last rule is *declined*: it runs on ``fused`` and is counted in
+    ``ctx.stats["pallas_declined"]`` with the primitive named
+    (``pallas_declined:<primitive>``).
 
 The stage chain itself is *reused as-is*: the kernel body calls each
 annotated function's original implementation on VMEM-resident tiles — the
-library function is still unmodified, it simply runs on a (1, BLOCK) block.
+library function is still unmodified, it simply runs on a ``(rows, 128)``
+block.
 
 The whole kernel launch (pad → pallas_call → unpad/combine) is wrapped in
 one jitted driver and pinned into the plan cache (``pinned_jit``), so warm
 executions of a cached plan reuse the compiled program instead of re-tracing
-``pallas_call`` every evaluation.
+``pallas_call`` every evaluation.  The driver is compiled explicitly the
+first time it sees an argument signature: anything the compiler raises
+there is a ``resilience.KernelRefused``, which propagates — a refused kernel
+is never silently replaced by another executor.
 """
 
 from __future__ import annotations
@@ -25,6 +34,7 @@ from typing import Any, Callable
 import jax
 import jax.numpy as jnp
 
+from repro.core import resilience
 from repro.core import split_types as st
 from repro.core.graph import NodeRef
 from repro.core.planner import Stage
@@ -36,22 +46,48 @@ from repro.core.stage_exec import (
     donatable_input_keys,
     effective_elements,
     get_executor,
-    mark_stream_consumed,
     note_materialized,
     note_trace,
     pinned_jit,
+    pinned_table,
     register_executor,
     stage_num_elements,
-    undonatable_stream_keys,
 )
+from repro.kernels import split_pipeline as sp
 
 
-def _effective_block(batch: int, n: int) -> int:
+def _effective_block(batch: int, n: int, cap: int) -> int:
     """The hardware block an element-count candidate actually compiles to
-    (mirrors ``split_pipeline_call``: clamp to n, round up to the 8x128
-    sublane x lane tile)."""
-    from repro.kernels.split_pipeline import MIN_BLOCK, _round_up
-    return max(MIN_BLOCK, _round_up(min(batch, max(n, 1)), MIN_BLOCK))
+    (mirrors ``padded_layout``: clamp to n, round up to the 8x128 sublane x
+    lane tile), bounded by the VMEM ``cap``."""
+    return min(sp.padded_layout(n, batch)[0], cap)
+
+
+def _itemsizes(x) -> int:
+    """Summed element width of the array leaves of ``x`` (value or aval)."""
+    return sum(jnp.dtype(leaf.dtype).itemsize for leaf in jax.tree.leaves(x)
+               if hasattr(leaf, "dtype"))
+
+
+def _block_cap(stage: Stage, ctx) -> int:
+    """Largest block whose VMEM footprint fits the chip's kernel limit:
+    double-buffered input and concat-output tiles, plus one live tile per
+    chain value (every node output) and the tail mask's index tiles."""
+    io = 0
+    for si in stage.inputs.values():
+        if si.split_type.splittable:
+            v = si.value
+            if isinstance(v, NodeRef):
+                v = ctx.graph.nodes[v.node_id].out_aval
+            io += _itemsizes(v)
+    live = 8                                  # int32 index + bool mask tiles
+    for node in stage.nodes:
+        size = _itemsizes(node.out_aval)
+        live += size
+        if (node.id in stage.escaping
+                and not isinstance(stage.out_types[node.id], st.ReduceSplit)):
+            io += size
+    return sp.block_cap(ctx.chip.kernel_vmem_limit_bytes, 2 * io + live)
 
 
 @register_executor("pallas")
@@ -60,7 +96,7 @@ class PallasExecutor(StageExecutor):
     anything the kernel cannot express falls back to the fused driver.
 
     Chunk handoff: an incoming ``ChunkStream`` is stacked DIRECTLY into the
-    kernel's padded ``(grid, BLOCK)`` launch layout (equal-grid fast path;
+    kernel's padded ``(rows, 128)`` launch layout (equal-grid fast path;
     ``rechunk`` for disagreeing grids) instead of being merged and re-padded;
     launch buffers the stage's handoff plan proves dead here are donated to
     the jitted launch driver under the same structural donate-key rules as
@@ -73,29 +109,31 @@ class PallasExecutor(StageExecutor):
         if not try_execute_stage_pallas(stage, concrete, ctx, self):
             get_executor("fused").execute(stage, concrete, ctx)
 
-    # -- block-shape-aware tuning (ROADMAP follow-up) ------------------------
+    # -- block-shape-aware tuning --------------------------------------------
     def tuning_candidates(self, stage: Stage, concrete: dict[tuple, Any], ctx,
                           est: int, n: int) -> list[int]:
-        """Round the §5.2 bracket to valid hardware block multiples.
+        """Round the §5.2 bracket to valid hardware blocks.
 
         The kernel only ever launches BLOCK = k x 1024 (8 sublanes x 128
-        lanes), so raw element-count candidates that resolve to the SAME
-        block are duplicates — measuring them would time one compiled shape
-        twice and call the timer noise a tuning decision.  Candidates are
-        therefore rounded to their effective block first and deduplicated;
-        the chosen block *shape* is recorded in the plan entry
-        (``PlanEntry.block_shape``)."""
+        lanes) up to the stage's VMEM cap, so raw element-count candidates
+        that resolve to the SAME block are duplicates — measuring them would
+        time one compiled shape twice and call the timer noise a tuning
+        decision.  Candidates are therefore rounded to their effective
+        block first and deduplicated; the chosen block *shape* is recorded
+        in the plan entry (``PlanEntry.block_shape``)."""
         from repro.core.stage_exec import candidate_batches
         if n <= 0:
             return [1]
+        cap = _block_cap(stage, ctx)
         seen: dict[int, int] = {}
         for c in candidate_batches(est, n):
-            b = _effective_block(c, n)
+            b = _effective_block(c, n, cap)
             seen.setdefault(b, min(b, n))
         return sorted(set(seen.values()))
 
     def note_pinned(self, stage: Stage, ctx, entry, batch: int, n: int) -> None:
-        entry.pin_block_shape(stage.id, (1, _effective_block(batch, n)))
+        block = _effective_block(batch, n, _block_cap(stage, ctx))
+        entry.pin_block_shape(stage.id, sp.block_shape(block))
 
 
 def _eligible(stage: Stage, concrete: dict[tuple, Any]) -> bool:
@@ -117,7 +155,6 @@ def _eligible(stage: Stage, concrete: dict[tuple, Any]) -> bool:
             if getattr(v, "shape", ()) not in ((), (1,)):
                 return False
     # reductions must not feed later nodes inside this stage
-    node_ids = {n.id for n in stage.nodes}
     for node in stage.nodes:
         if isinstance(stage.out_types[node.id], st.ReduceSplit):
             for other in stage.nodes:
@@ -127,12 +164,12 @@ def _eligible(stage: Stage, concrete: dict[tuple, Any]) -> bool:
     return True
 
 
-def _build_pallas_driver(stage: Stage, split_ckeys: list[tuple],
-                         bcast_ckeys: list[tuple], esc_pos: list[int],
-                         out_kinds: list[tuple[str, str]], out_dtypes: list,
-                         batch: int, interpret: bool) -> Callable:
-    from repro.kernels.split_pipeline import padded_layout, split_pipeline_call_2d
-
+def _make_chain_fn(stage: Stage, split_ckeys: list[tuple],
+                   bcast_ckeys: list[tuple], esc_pos: list[int],
+                   out_kinds: list[tuple[str, str]]) -> Callable:
+    """The stage chain as the kernel body calls it: ``chain_fn(blocks,
+    bcasts)`` -> escaping values (a reduce output is its PRE-reduction
+    block; the kernel masks the tail padding and reduces)."""
     plan = chain_plan(stage)
     reduce_keys = {("n", stage.pos[n.id]) for n in stage.nodes
                    if isinstance(stage.out_types[n.id], st.ReduceSplit)}
@@ -151,41 +188,74 @@ def _build_pallas_driver(stage: Stage, split_ckeys: list[tuple],
                 kw[name] = env[key]
                 if src is None:
                     src = kw[name]
-            env[out_key] = fn.fn(**kw)        # unmodified library fn
             if out_key in reduce_keys:
-                # The kernel applies the masked reduction itself (padding must
-                # be excluded), so hand it the PRE-reduction block.
+                # Nothing in the stage reads a reduction (eligibility), and
+                # the kernel reduces the block itself, tail masked.
                 reduce_src[out_key] = src
-        outs = []
-        for p, (kind, _) in zip(esc_pos, out_kinds):
-            outs.append(reduce_src[("n", p)] if kind == "reduce" else env[("n", p)])
-        return outs
+                continue
+            env[out_key] = fn.fn(**kw)        # unmodified library fn
+        return [reduce_src[("n", p)] if kind == "reduce" else env[("n", p)]
+                for p, (kind, _) in zip(esc_pos, out_kinds)]
 
+    return chain_fn
+
+
+class _KernelLaunch:
+    """The jitted launch driver, compiled explicitly the first time each
+    argument signature arrives.  That compile is where the TPU compiler
+    refuses a kernel (block layout, unimplemented primitive, VMEM), so
+    whatever it raises becomes ``KernelRefused`` — told apart from a
+    failure of the run itself.  The call after it reuses the executable."""
+
+    def __init__(self, jitted: Callable, where: str):
+        self._jitted = jitted
+        self._where = where
+        self._compiled: set = set()
+
+    def __call__(self, donated: dict, rest: dict, bcast_vals: list, n: int):
+        args = (donated, rest, bcast_vals)
+        sig = (n, jax.tree.structure(args),
+               tuple(jax.typeof(x) for x in jax.tree.leaves(args)))
+        if sig not in self._compiled:
+            try:
+                self._jitted.lower(*args, n).compile()
+            except Exception as e:  # noqa: BLE001 — every compiler refusal
+                raise resilience.KernelRefused(
+                    f"{self._where}: {type(e).__name__}: {e}") from e
+            self._compiled.add(sig)
+        return self._jitted(*args, n)
+
+
+def _build_pallas_driver(stage: Stage, chain_fn: Callable, n_split: int,
+                         out_kinds: list[tuple[str, str]], out_dtypes: list,
+                         block: int, vmem_limit: int) -> _KernelLaunch:
     def driver(donated: dict, rest: dict, bcast_vals, n: int):
-        # Launch buffers arrive prebuilt in the padded (grid, BLOCK) layout
+        # Launch buffers arrive prebuilt in the padded (rows, 128) layout
         # (position-keyed so donated and retained buffers reassemble in
         # split-key order); the true length ``n`` is a static argument —
         # the tail mask must never come from a stale closure.
         note_trace()
         bufs = {**rest, **donated}
-        split2d = [bufs[i] for i in range(len(split_ckeys))]
-        block, _n_pad, _grid = padded_layout(n, batch)
-        return split_pipeline_call_2d(
+        split2d = [bufs[i] for i in range(n_split)]
+        return sp.split_pipeline_call_2d(
             chain_fn, split2d, bcast_vals, out_kinds, out_dtypes, n, block,
-            interpret=interpret)
+            vmem_limit)
 
-    return jax.jit(driver, static_argnums=(3,), donate_argnums=(0,))
+    return _KernelLaunch(
+        jax.jit(driver, static_argnums=(3,), donate_argnums=(0,)),
+        f"stage {stage.id} split-pipeline kernel, block {block}")
 
 
 def _to_launch_layout(v: Any, n: int, block: int, stage: Stage, ck: tuple,
                       ctx) -> tuple[Any, bool]:
-    """One split input as its ``(grid, BLOCK)`` launch buffer.
+    """One split input as its ``(rows, 128)`` launch buffer.
 
     Returns ``(buffer, fresh)`` — ``fresh`` means the buffer was assembled
-    here (stack/pad copies) and may be donated without endangering anyone
-    else's storage.  A handed-off ``ChunkStream`` stacks its chunk list
-    straight into the layout (equal-grid fast path; ``rechunk`` for
-    disagreeing grids) — ``materialize()`` is never called.
+    here (stack/pad/reshape copies) and may be donated without endangering
+    anyone else's storage; a stream's buffer always is.  A handed-off
+    ``ChunkStream`` stacks its chunk list straight into the layout
+    (equal-grid fast path; ``rechunk`` for disagreeing grids) —
+    ``materialize()`` is never called.
 
     Building the buffer EAGERLY (outside the pinned driver) costs a few
     extra dispatches per call, and is deliberate twice over: the driver's
@@ -194,22 +264,21 @@ def _to_launch_layout(v: Any, n: int, block: int, stage: Stage, ck: tuple,
     padding would retrace on every flap, breaking the warm zero-retrace
     invariant), and only an argument buffer can be DONATED (a padded
     intermediate built inside the jit has no donation story)."""
-    from repro.kernels.split_pipeline import _round_up, pad_to_layout
-
     if not isinstance(v, ChunkStream):
-        return pad_to_layout(v, n, block), _round_up(n, block) > n
+        return sp.pad_to_layout(v, n, block), sp._round_up(n, block) > n
 
     grid_ranges = batch_ranges(n, block)
-    # scan→pallas: a carry-form stream whose batch IS the block passes its
-    # (k, BLOCK) main buffer through untouched.
+    # scan→pallas: a carry-form stream whose batch IS the block re-views
+    # its (k, block) main buffer as rows of 128 lanes — no chunk list.
     if (v.stacked is not None and v._chunks is None
             and v.uniform_batch() == block
             and isinstance(v.stacked, jax.Array) and v.stacked.ndim == 2):
-        if v.tail is None:
-            return v.stacked, False
-        pad = block - int(v.tail.shape[0])
-        tail_row = jnp.pad(v.tail, (0, pad)).reshape(1, block)
-        return jnp.concatenate([v.stacked, tail_row], axis=0), True
+        rows = [v.stacked]
+        if v.tail is not None:
+            pad = block - int(v.tail.shape[0])
+            rows.append(jnp.pad(v.tail, (0, pad)).reshape(1, block))
+        buf = rows[0] if len(rows) == 1 else jnp.concatenate(rows, axis=0)
+        return buf.reshape(-1, sp.LANES), True
 
     chunks, ranges = v.chunks, v.ranges
     if ranges != grid_ranges:
@@ -222,12 +291,28 @@ def _to_launch_layout(v: Any, n: int, block: int, stage: Stage, ck: tuple,
     main = chunks[:-1] if ragged else chunks
     rows = []
     if main:
-        rows.append(jnp.stack(main))
+        rows.append(jnp.stack(main).reshape(-1, sp.LANES))
     if ragged:
         rows.append(jnp.pad(chunks[-1], (0, block - sizes[-1]))
-                    .reshape(1, block))
+                    .reshape(-1, sp.LANES))
     buf = rows[0] if len(rows) == 1 else jnp.concatenate(rows, axis=0)
     return buf, True
+
+
+def _declined(stage: Stage, concrete: dict[tuple, Any], ctx,
+              split_keys: list, bcast_keys: list, chain_fn: Callable) -> list:
+    """The chain's primitives the kernel cannot lower (empty: launch it).
+    Decided once per stage template and kept with its pinned drivers."""
+    table = pinned_table(stage, ctx)
+    key = (stage.id, "pallas_unlowerable")
+    if key not in table:
+        def dtype_of(v):
+            v = v.aval if isinstance(v, ChunkStream) else v
+            return getattr(v, "dtype", None) or jnp.result_type(v)
+        table[key] = sp.unlowerable_primitives(
+            chain_fn, [dtype_of(concrete[k]) for k in split_keys],
+            [dtype_of(concrete[k]) for k in bcast_keys])
+    return table[key]
 
 
 def try_execute_stage_pallas(stage: Stage, concrete: dict[tuple, Any], ctx,
@@ -239,13 +324,6 @@ def try_execute_stage_pallas(stage: Stage, concrete: dict[tuple, Any], ctx,
     bcast_keys = [k for k, si in stage.inputs.items() if not si.split_type.splittable]
     if not split_keys:
         return False
-
-    executor = executor or get_executor("pallas")
-    n = effective_elements(ctx, stage_num_elements(stage, concrete, ctx.pedantic))
-    if n == 0:
-        return False                   # empty split: no grid to launch
-    batch = executor.choose_batch(stage, concrete, ctx, n)
-    block = _effective_block(batch, n)
 
     escape_ids = sorted(stage.escaping)
     esc_pos = [stage.pos[nid] for nid in escape_ids]
@@ -259,55 +337,64 @@ def try_execute_stage_pallas(stage: Stage, concrete: dict[tuple, Any], ctx,
         else:
             out_kinds.append(("concat", ""))
         out_dtypes.append(node.out_aval.dtype)
+    chain_fn = _make_chain_fn(
+        stage, [stage.ckey(k) for k in split_keys],
+        [stage.ckey(k) for k in bcast_keys], esc_pos, out_kinds)
 
-    interpret = jax.default_backend() != "tpu"
+    unlowerable = _declined(stage, concrete, ctx, split_keys, bcast_keys,
+                            chain_fn)
+    if unlowerable:
+        ctx.stats["pallas_declined"] += 1
+        for prim in unlowerable:
+            ctx.stats[f"pallas_declined:{prim}"] += 1
+        return False
+
+    executor = executor or get_executor("pallas")
+    n = effective_elements(ctx, stage_num_elements(stage, concrete, ctx.pedantic))
+    if n == 0:
+        return False                   # empty split: no grid to launch
+    batch = executor.choose_batch(stage, concrete, ctx, n)
+    block = _effective_block(batch, n, _block_cap(stage, ctx))
+
     entry = getattr(ctx, "_plan_entry", None)
     if entry is not None:
         # The block SHAPE this launch compiles to, persisted for warm starts
         # and EXPLAIN tooling (idempotent: no-op when already recorded).
-        entry.pin_block_shape(stage.id, (1, block))
+        entry.pin_block_shape(stage.id, sp.block_shape(block))
 
     # Structural donate set (shared rules with the fused/scan drivers): the
     # positions are part of the pinned variant key, so warm calls never flap.
     donate_cks = set(donatable_input_keys(stage, ctx))
     donate_pos = tuple(i for i, k in enumerate(split_keys)
                        if stage.ckey(k) in donate_cks)
-    unsafe = undonatable_stream_keys(
-        stage, concrete, ctx, tuple(donate_cks)) if donate_pos else set()
 
+    vmem_limit = ctx.chip.kernel_vmem_limit_bytes
     driver = pinned_jit(
-        stage, ctx, "pallas", (tuple(esc_pos), batch, interpret, donate_pos),
+        stage, ctx, "pallas",
+        (tuple(esc_pos), block, donate_pos),
         lambda: _build_pallas_driver(
-            stage, [stage.ckey(k) for k in split_keys],
-            [stage.ckey(k) for k in bcast_keys], esc_pos,
-            out_kinds, out_dtypes, batch, interpret))
+            stage, chain_fn, len(split_keys), out_kinds, out_dtypes, block,
+            vmem_limit))
 
     donated: dict[int, Any] = {}
     rest: dict[int, Any] = {}
-    consumed_keys: set = set()
     for i, k in enumerate(split_keys):
-        v = concrete[k]
-        buf, fresh = _to_launch_layout(v, n, block, stage, stage.ckey(k), ctx)
+        buf, fresh = _to_launch_layout(concrete[k], n, block, stage,
+                                       stage.ckey(k), ctx)
         if i not in donate_pos:
             rest[i] = buf
-            continue
-        if fresh:
+        elif fresh:
             donated[i] = buf           # our own assembly: donation is free
-        elif stage.ckey(k) in unsafe or not isinstance(v, ChunkStream):
-            # Observable stream pass-through, or a whole array whose padded
-            # view may alias the producer's retained result: donate a copy.
+        else:
+            # A whole array whose launch view may alias the producer's
+            # retained result: donate a copy.
             donated[i] = jnp.array(buf)
             ctx.stats["donation_copies"] += 1
-        else:
-            donated[i] = buf           # dead carry pass-through: real donation
-            consumed_keys.add(stage.ckey(k))
     if donated:
         ctx.stats["donated_chunks"] += len(donated)
 
     outs = driver(donated, rest, [concrete[k] for k in bcast_keys], n)
-    from repro.kernels.split_pipeline import unpad_outputs
-    results = unpad_outputs(outs, out_kinds, n, block)
-    mark_stream_consumed(stage, concrete, ctx, consumed_keys)
+    results = sp.unpad_outputs(outs, out_kinds, n)
     for nid, res in zip(escape_ids, results):
         node = next(nd for nd in stage.nodes if nd.id == nid)
         node.result = res

@@ -27,10 +27,12 @@ Three legs:
    demotes along ``DEGRADE_ORDER`` (pallas → scan/fused → pipelined →
    eager) until the stage completes, quarantines the broken choice in the
    plan entry (persisted — warm calls and restarted processes skip it) and
-   ages the quarantine so the executor is eventually retried.  Chunk-loop
-   resource exhaustion is handled below the ladder: ``core/executor.py``
-   halves the chunk batch with bounded retries and re-pins the surviving
-   size into the tuner state.
+   ages the quarantine so the executor is eventually retried.  A kernel
+   the compiler refuses (``KernelRefused``) is not recoverable: it
+   propagates, so a run never reports one path while running another.
+   Chunk-loop resource exhaustion is handled below the ladder:
+   ``core/executor.py`` halves the chunk batch with bounded retries and
+   re-pins the surviving size into the tuner state.
 
 3. **Shared error taxonomy.**  ``TRANSIENT_ERRORS`` / ``PROBE_ERRORS``
    replace the runtime's bare ``except Exception`` swallows: probe/measure
@@ -57,10 +59,11 @@ log = logging.getLogger("repro.resilience")
 
 __all__ = [
     "BOUNDARIES", "DEGRADE_ORDER", "FaultPlan", "FaultSpec", "InjectedFault",
-    "InjectedResourceExhausted", "PROBE_ERRORS", "QUARANTINE_TTL", "StepFailure",
-    "StepTimer", "FaultConfig", "TRANSIENT_ERRORS", "clear_events", "events",
-    "inject_faults", "is_resource_exhausted", "maybe_fail", "note_swallowed",
-    "record_event", "run_stage", "run_with_restarts", "stats", "with_retries",
+    "InjectedResourceExhausted", "KernelRefused", "PROBE_ERRORS",
+    "QUARANTINE_TTL", "StepFailure", "StepTimer", "FaultConfig",
+    "TRANSIENT_ERRORS", "clear_events", "events", "inject_faults",
+    "is_resource_exhausted", "maybe_fail", "note_swallowed", "record_event",
+    "run_stage", "run_with_restarts", "stats", "with_retries",
 ]
 
 
@@ -79,6 +82,16 @@ class InjectedFault(RuntimeError):
 
 class InjectedResourceExhausted(InjectedFault):
     """Injected stand-in for an XLA RESOURCE_EXHAUSTED / host MemoryError."""
+
+
+class KernelRefused(Exception):
+    """The compiler refused to lower or compile a kernel launch.
+
+    A deterministic fault, not a transient one: the same launch is refused
+    every time.  It derives from neither ``TRANSIENT_ERRORS`` nor
+    ``PROBE_ERRORS``, so no handler here catches it — the ladder does not
+    demote around it, the chunk loop does not halve for it, and the tuner
+    and ``auto`` do not swallow it.  It propagates to the caller."""
 
 
 #: errors a *retry* can plausibly fix: infrastructure/runtime failures

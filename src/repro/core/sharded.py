@@ -84,22 +84,6 @@ class ShardedExecutor(StageExecutor):
         return max(m, (s // m) * m)
 
 
-def _shard_map(fn, mesh, in_specs, out_specs):
-    """shard_map across jax versions: top-level ``jax.shard_map`` with
-    ``check_vma`` (new) vs ``jax.experimental.shard_map`` with ``check_rep``."""
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        return sm(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_vma=False)
-    from jax.experimental.shard_map import shard_map as sm
-    try:
-        return sm(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_rep=False)
-    except TypeError:
-        return sm(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_vma=False)
-
-
 def _pspec_for(split_type: st.SplitType, ndim: int, axes: tuple[str, ...]):
     ax = split_axis_of(split_type)
     if ax is None:
@@ -145,11 +129,12 @@ def _build_sharded_driver(stage: Stage, mesh, axes, in_specs, out_specs,
         return tuple(outs)
 
     return jax.jit(
-        _shard_map(
+        jax.shard_map(
             local_fn,
             mesh=mesh,
             in_specs=tuple(in_specs),
             out_specs=tuple(out_specs),
+            check_vma=False,
         )
     )
 
@@ -297,7 +282,13 @@ def execute_stage_sharded(stage: Stage, concrete: dict[tuple, Any], ctx,
         lambda: _build_sharded_driver(
             stage, mesh, axes, in_specs, out_specs, in_ckeys, in_split_types,
             esc_pos, out_types_by_pos, n_local, batch, whole))
-    results = shard_fn(*[concrete[k] for k in in_keys])
+    # An array committed to one device (or to another mesh) cannot enter
+    # the shard_map as it is: place every array input on this mesh first
+    # (a no-op for one already placed so).
+    args = [jax.device_put(v, NamedSharding(mesh, spec))
+            if isinstance(v, jax.Array) else v
+            for v, spec in zip((concrete[k] for k in in_keys), in_specs)]
+    results = shard_fn(*args)
     ctx.stats["sharded_stages"] += 1
     # merge() of a single piece is the identity for concat-style types.
     by_pos = dict(zip(esc_pos, results))

@@ -771,6 +771,19 @@ def finish_stage(stage: Stage, partials: dict[int, list[Any]],
 # ---------------------------------------------------------------------------
 
 
+def pinned_table(stage: Stage, ctx) -> dict:
+    """The in-process table ``stage``'s pinned drivers (and decisions that
+    hold for them) live in: the plan entry's executable table when the
+    stage belongs to a cached plan, else the Stage instance itself."""
+    entry = getattr(ctx, "_plan_entry", None)
+    table = entry.exec_table() if entry is not None else None
+    if table is None:
+        table = getattr(stage, "_jit_cache", None)
+        if table is None:
+            table = stage._jit_cache = {}
+    return table
+
+
 def pinned_jit(stage: Stage, ctx, kind: str, extra_key: tuple,
                build: Callable[[], Callable]) -> Callable:
     """One compiled driver per (plan entry, stage position, kind, extra_key).
@@ -787,12 +800,7 @@ def pinned_jit(stage: Stage, ctx, kind: str, extra_key: tuple,
     warmup-then-time runs).
     """
     key = (stage.id, kind) + tuple(extra_key)
-    entry = getattr(ctx, "_plan_entry", None)
-    table = entry.exec_table() if entry is not None else None
-    if table is None:
-        table = getattr(stage, "_jit_cache", None)
-        if table is None:
-            table = stage._jit_cache = {}
+    table = pinned_table(stage, ctx)
     fn = table.get(key)
     if fn is None:
         resilience.maybe_fail("compile", f"stage {stage.id} {kind}")

@@ -1,13 +1,18 @@
 """Hardware constants for the roofline model and the Mozart batch heuristic.
 
-The TARGET is TPU v5e (the runtime container is CPU-only; Pallas kernels are
-validated in interpret mode).  The paper's batch-size heuristic sizes one
-pipeline batch to fit in fast memory: L2 on CPU, VMEM on TPU.
+The TARGET is TPU v5e.  Pallas kernels run compiled on a TPU and in
+interpret mode on any other backend (the CPU test tier);
+tests/test_tpu_compile.py compiles the main path's kernel for a described
+v5e topology, and ``chip_smoke.py`` runs the main path on the chip.
+``CHIPS`` maps a device's ``device_kind`` to its constants (``chip_for``).
+The paper's batch-size heuristic sizes one pipeline batch to fit in fast
+memory: L2 on CPU, VMEM on TPU.
 """
 
 from __future__ import annotations
 
 import dataclasses
+import os
 
 
 @dataclasses.dataclass(frozen=True)
@@ -31,9 +36,15 @@ class Chip:
     # fused chains).  Amortized over a session; charged once per stage.
     compile_overhead_s: float = 50e-3
 
+    @property
+    def kernel_vmem_limit_bytes(self) -> int:
+        """Scoped VMEM limit a Pallas kernel is compiled with: three quarters
+        of the fast memory, leaving the rest to the compiler's own scratch."""
+        return self.vmem_bytes * 3 // 4
 
-# Target accelerator (per the assignment brief):
-#   197 TFLOP/s bf16 per chip; 819 GB/s HBM; ~50 GB/s/link ICI.
+
+# TPU v5e peaks: Google Cloud documentation, "TPU v5e" — 197 TFLOP/s bf16,
+# 16 GB HBM at 819 GB/s, 1,600 Gbit/s chip-to-chip interconnect (4 links).
 TPU_V5E = Chip(
     name="tpu_v5e",
     peak_bf16_flops=197e12,
@@ -62,6 +73,45 @@ CPU_HOST = Chip(
 )
 
 TARGET = TPU_V5E
+
+#: chip constants keyed by ``jax.Device.device_kind``.
+CHIPS = {
+    "TPU v5 lite": TPU_V5E,
+    "cpu": CPU_HOST,
+}
+
+
+def chip_for(device) -> Chip:
+    """The constants of ``device`` (a ``jax.Device``).  A kind the table
+    lacks is an error, never a default: wrong peaks would silently skew
+    every batch size and roofline derived from them."""
+    try:
+        return CHIPS[device.device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no chip constants for device_kind {device.device_kind!r}; "
+            f"known: {sorted(CHIPS)}") from None
+
+
+#: the checkout this package runs from (``src/repro/hardware.py`` -> root).
+_CHECKOUT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+
+
+def use_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache; entry points call this,
+    library imports never do.
+
+    ``JAX_COMPILATION_CACHE_DIR``, when set, is JAX's own setting and is
+    left alone.  Otherwise the cache lives at ``<checkout>/.jax_cache``: a
+    fixed path, because the path is part of what a later run must find."""
+    import jax
+
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not path:
+        path = os.path.join(_CHECKOUT, ".jax_cache")
+        jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
 # ---------------------------------------------------------------------------

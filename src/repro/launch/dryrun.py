@@ -35,7 +35,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.configs.registry import ARCH_IDS, get_config
 from repro.configs.shapes import SHAPES, applicable, enc_len_for, input_specs
-from repro.launch.mesh import data_axes_of, dp_extent, make_production_mesh, set_mesh
+from repro.launch.mesh import data_axes_of, dp_extent, make_production_mesh
 from repro.launch import shardings as shd
 from repro.models import lm
 from repro.models import shard_ctx
@@ -206,7 +206,7 @@ def dryrun_cell(arch: str, shape_name: str, *, multi_pod: bool = False,
                                                 "grad_norm": jnp.float32(0)})),
                                    mesh)),
             )
-            with set_mesh(mesh):
+            with jax.set_mesh(mesh):
                 lowered = jitted.lower(params_aval, opt_aval, batch_aval)
         elif shape.kind == "prefill":
             batch_aval = input_specs(cfg, shape)
@@ -224,7 +224,7 @@ def dryrun_cell(arch: str, shape_name: str, *, multi_pod: bool = False,
                 out_shardings=(NamedSharding(mesh, tok_spec),
                                shd.named(c_specs, mesh)),
             )
-            with set_mesh(mesh):
+            with jax.set_mesh(mesh):
                 lowered = jitted.lower(params_aval, batch_aval, caches_aval)
         else:  # decode
             spec_in = input_specs(cfg, shape)
@@ -250,7 +250,7 @@ def dryrun_cell(arch: str, shape_name: str, *, multi_pod: bool = False,
                 out_shardings=(NamedSharding(mesh, P(bspec, None)),
                                shd.named(c_specs, mesh)),
             )
-            with set_mesh(mesh):
+            with jax.set_mesh(mesh):
                 lowered = jitted.lower(*args)
 
         t_lower = time.time() - t0

@@ -25,15 +25,6 @@ def make_host_mesh(n_data: int = 1, n_model: int = 1):
     return jax.make_mesh((n_data, n_model), ("data", "model"))
 
 
-def set_mesh(mesh):
-    """``jax.set_mesh`` across jax versions: older releases spell the same
-    context manager as entering the Mesh object itself."""
-    sm = getattr(jax, "set_mesh", None)
-    if sm is not None:
-        return sm(mesh)
-    return mesh
-
-
 def data_axes_of(mesh) -> tuple[str, ...]:
     return tuple(a for a in ("pod", "data") if a in mesh.axis_names)
 

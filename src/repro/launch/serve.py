@@ -220,6 +220,8 @@ def main():
                          "MOZART_PLAN_CACHE)")
     args = ap.parse_args()
 
+    from repro import hardware
+    hardware.use_compile_cache()
     cfg = (get_smoke_config(args.arch) if args.smoke else get_config(args.arch))
     params = tfm.init_model(jax.random.PRNGKey(0), cfg)
     rng = np.random.default_rng(0)

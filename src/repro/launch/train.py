@@ -27,7 +27,7 @@ from repro.ckpt import checkpoint as ckpt
 from repro.configs.registry import ARCH_IDS, get_config, get_smoke_config
 from repro.data.pipeline import DataPipeline
 from repro.launch import shardings as shd
-from repro.launch.mesh import data_axes_of, dp_extent, make_host_mesh, set_mesh
+from repro.launch.mesh import data_axes_of, dp_extent, make_host_mesh
 from repro.models import lm
 from repro.models import transformer as tfm
 from repro.models.config import ModelConfig
@@ -87,7 +87,7 @@ def train(cfg: ModelConfig, *, steps: int, batch: int, seq: int,
         params, opt_state = restored["params"], restored["opt"]
         log.info("resumed from step %d", start)
     else:
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             params = jax.jit(functools.partial(tfm.init_model, cfg=cfg),
                              out_shardings=p_shard)(jax.random.PRNGKey(seed))
             opt_state = jax.jit(adamw.init, out_shardings=o_shard)(params)

@@ -18,6 +18,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro import hardware
 from repro.core import mozart, plan_cache, resilience, splittable, Along
 from repro.core import annotated_numpy as anp
 from repro.core.resilience import (FaultConfig, FaultPlan, FaultSpec,
@@ -267,6 +268,52 @@ def test_sustained_oom_bounded_then_ladder_finishes_on_eager(oracle):
 class _Ctx:
     def __init__(self, **stats):
         self.stats = dict(stats)
+
+
+#: fast memory sized so the tuner brackets several kernel blocks below N.
+_TUNING_CHIP = hardware.Chip(
+    name="resilience_tuning_chip", peak_bf16_flops=1e11, hbm_bandwidth=2e10,
+    ici_link_bandwidth=1e10, ici_links=1, hbm_bytes=2**30,
+    vmem_bytes=256 * 1024, mozart_c=0.1)
+
+
+@pytest.mark.parametrize("when", ["cold", "tuning"])
+def test_refused_kernel_compile_propagates_without_demotion(monkeypatch, when):
+    """A kernel the compiler refuses is a deterministic fault: the error
+    propagates (as KernelRefused, caused by the compiler's error) from the
+    first launch and from the tuner's samples alike — never demoted down
+    the ladder, never halved, never swallowed."""
+    from repro.kernels import split_pipeline as sp
+
+    sessions = []
+
+    def run():
+        with mozart.session(executor="pallas", chip=_TUNING_CHIP) as ctx:
+            sessions.append(ctx)
+            c, s = quickstart(X, Y)
+            float(s)
+        return ctx
+
+    def refuse(*args, **kwargs):
+        raise ValueError("The Pallas TPU lowering currently requires that the "
+                         "last two dimensions of your block shape are "
+                         "divisible by 8 and 128 respectively")
+
+    plan_cache.clear()
+    if when == "tuning":
+        assert run().stats["pallas_stages"] == 1     # plan (miss); works
+    monkeypatch.setattr(sp, "split_pipeline_call_2d", refuse)
+    with pytest.raises(resilience.KernelRefused) as info:
+        run()
+    assert isinstance(info.value.__cause__, ValueError)
+    stats = sessions[-1].stats
+    assert stats["exec_demotions"] == 0
+    assert stats["chunk_oom_halvings"] == 0
+    assert stats["swallowed_errors"] == 0
+    for code in ("MZ402", "MZ403", "MZ404", "MZ406"):
+        assert resilience.stats[code] == 0, code
+    (entry,) = plan_cache.entries()
+    assert not entry.quarantined
 
 
 def test_sanitizer_errors_are_never_demoted_around():

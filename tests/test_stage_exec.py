@@ -452,20 +452,28 @@ def test_future_exposes_split_type():
 class TestPallasBlockShapeTuning:
     def test_candidates_round_to_hardware_blocks(self):
         """Raw element-count candidates resolving to the SAME 8x128 block are
-        duplicates — the tuner must measure each compiled block shape once."""
+        duplicates — the tuner must measure each compiled block shape once,
+        and never one above the stage's VMEM cap."""
         from repro.core.stage_exec import get_executor
         from repro.kernels.split_pipeline import MIN_BLOCK
         ex = get_executor("pallas")
-        ctx = mozart.MozartContext(executor="pallas")
         n = 1 << 16
+        x = jnp.linspace(0.0, 1.0, n, dtype=jnp.float32)
+        with mozart.session(executor="pallas") as ctx:
+            out = anp.sum(anp.multiply(anp.exp(x), 0.5))
+            (stage,) = ctx.last_plan()
+            float(out)
         # est=700 -> raw bracket {350, 700, 1400} all round to 1024/2048
-        cands = ex.tuning_candidates(None, {}, ctx, 700, n)
+        cands = ex.tuning_candidates(stage, {}, ctx, 700, n)
         assert cands == sorted(set(cands))
         assert all(c == n or c % MIN_BLOCK == 0 for c in cands)
         assert len(cands) <= 2
         # huge estimate clamps to n; empty split degenerates to [1]
-        assert ex.tuning_candidates(None, {}, ctx, 10 * n, n) == [n]
-        assert ex.tuning_candidates(None, {}, ctx, 512, 0) == [1]
+        assert ex.tuning_candidates(stage, {}, ctx, 10 * n, n) == [n]
+        assert ex.tuning_candidates(stage, {}, ctx, 512, 0) == [1]
+        # a chip whose VMEM holds less than two blocks caps every candidate
+        tiny = mozart.MozartContext(executor="pallas", chip=TINY_CHIP)
+        assert ex.tuning_candidates(stage, {}, tiny, 10 * n, n) == [MIN_BLOCK]
 
     def test_chosen_block_shape_recorded_in_plan_entry(self):
         x = jnp.linspace(0.0, 1.0, 6000, dtype=jnp.float32)
@@ -479,13 +487,14 @@ class TestPallasBlockShapeTuning:
         run(); run(); _, ctx = run()
         (entry,) = plan_cache.entries()
         assert entry.block_shape, "pallas recorded no block shape"
-        from repro.kernels.split_pipeline import MIN_BLOCK
-        for sid, (sub, block) in entry.block_shape.items():
-            assert sub == 1 and block % MIN_BLOCK == 0
-            # the recorded shape is what the pinned batch compiles to
+        from repro.kernels.split_pipeline import LANES, SUBLANES, padded_layout
+        for sid, (sub, lanes) in entry.block_shape.items():
+            assert lanes == LANES and sub % SUBLANES == 0
+            # the recorded shape is what the pinned batch compiles to (this
+            # chain's VMEM cap on CPU_HOST is far above 6000 elements)
             if sid in entry.tuned_batch:
-                from repro.core.pallas_exec import _effective_block
-                assert block == _effective_block(entry.tuned_batch[sid], 6000)
+                block, _, _ = padded_layout(6000, entry.tuned_batch[sid])
+                assert sub * lanes == block
 
     def test_block_shape_persists(self, tmp_path):
         x = jnp.linspace(0.0, 1.0, 6000, dtype=jnp.float32)
@@ -502,3 +511,44 @@ class TestPallasBlockShapeTuning:
         assert plan_cache.load(path) == 1
         (loaded,) = plan_cache.entries()
         assert dict(loaded.block_shape) == want
+
+
+# ---------------------------------------------------------------------------
+# Chip constants from the device, and the compile-cache location
+# ---------------------------------------------------------------------------
+
+
+class _Device:
+    def __init__(self, kind):
+        self.device_kind = kind
+
+
+class TestChipConstants:
+    def test_chip_for_reads_device_kind(self):
+        assert hardware.chip_for(_Device("TPU v5 lite")) is hardware.TPU_V5E
+        assert hardware.chip_for(jax.devices()[0]) is hardware.CPU_HOST
+        with pytest.raises(ValueError, match="no chip constants"):
+            hardware.chip_for(_Device("TPU v9 imaginary"))
+
+    def test_kernel_vmem_limit_leaves_compiler_headroom(self):
+        limit = hardware.TPU_V5E.kernel_vmem_limit_bytes
+        assert 0 < limit < hardware.TPU_V5E.vmem_bytes
+
+    def test_compile_cache_env_wins_and_nothing_else_is_set(self, monkeypatch):
+        before = jax.config.jax_compilation_cache_dir
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere/cache")
+        assert hardware.use_compile_cache() == "/elsewhere/cache"
+        assert jax.config.jax_compilation_cache_dir == before
+
+    def test_compile_cache_defaults_to_checkout(self, monkeypatch):
+        import os
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        before = jax.config.jax_compilation_cache_dir
+        try:
+            path = hardware.use_compile_cache()
+            assert path == os.path.join(repo, ".jax_cache")
+            assert jax.config.jax_compilation_cache_dir == path
+            assert hardware.use_compile_cache() == path     # fixed path
+        finally:
+            jax.config.update("jax_compilation_cache_dir", before)
