@@ -30,7 +30,7 @@ def main():
     y = jnp.ones(1_000_000, jnp.float32)
 
     # --- 2. run lazily under a Mozart session ------------------------------
-    with mozart.session(executor="scan", log=False) as ctx:
+    with mozart.session(executor="scan") as ctx:
         a = saxpy(x, y)                # -> Future (nothing ran yet)
         b = anp.exp(a)                 # library ops compose with yours
         c = anp.multiply(b, 0.5)

@@ -238,8 +238,6 @@ class AutoExecutor(StageExecutor):
             name = choose(feats, ctx, timings, blocked)
         ctx.stats["auto_stages"] += 1
         ctx.stats[f"auto_pick_{name}"] += 1
-        if ctx.log:
-            print(f"[mozart] stage {stage.id}: auto -> {name}")
         # Delegate through the degradation ladder (no re-tick: this dispatch
         # already aged the quarantine at the outer run_stage).
         resilience.run_stage(name, stage, graph, ctx, _tick=False)

@@ -38,6 +38,7 @@ import jax.numpy as jnp
 from repro.core import resilience
 from repro.core.graph import NodeRef
 from repro.core.planner import Stage
+from repro.core.trace import count_layout, span
 from repro.core.stage_exec import (
     ChunkStream,
     PedanticError,
@@ -92,21 +93,21 @@ def _build_fused_driver(stage: Stage, esc: tuple[int, ...],
         # Handed-off chunk buffers whose stream dies after this stage arrive
         # as a separate (donated) argument: XLA reuses the dead intermediate's
         # memory for this chunk's outputs instead of allocating fresh buffers.
-        def fused_driver_donate(donated, env):
+        def mozart_fused_driver_donate(donated, env):
             note_trace()
             env = dict(env)
             env.update(donated)
             run_plan(plan, env)
             return {p: env[("n", p)] for p in esc}
 
-        return jax.jit(fused_driver_donate, donate_argnums=(0,))
+        return jax.jit(mozart_fused_driver_donate, donate_argnums=(0,))
 
-    def fused_driver(env):
+    def mozart_fused_driver(env):
         note_trace()
         run_plan(plan, env)
         return {p: env[("n", p)] for p in esc}
 
-    return jax.jit(fused_driver)
+    return jax.jit(mozart_fused_driver)
 
 
 class ChunkedExecutor(StageExecutor):
@@ -217,36 +218,38 @@ class ChunkedExecutor(StageExecutor):
                                   lambda: _build_fused_driver(stage, esc, donate))
 
         partials: dict[int, list[Any]] = {p: [] for p in esc}
-        for i, (s, e) in enumerate(ranges):
-            resilience.maybe_fail("chunk", f"stage {stage.id} chunk [{s},{e})")
-            env = chunk_env_for(stage, concrete, s, e, ctx.pedantic,
-                                chunk_index=i, force_slice=donate)
-            if mode == "pipelined":
-                run_chain(stage, env, jit_each=True)
-                ctx.stats["calls"] += len(stage.nodes)
-                outs = {p: env[("n", p)] for p in esc}
-            else:
-                if donate:
-                    # Observable streams donate a defensive COPY — their own
-                    # chunk buffers must survive a later Future.value.
-                    donated = {}
-                    for k in donate:
-                        v = env.pop(k)
-                        if k in unsafe:
-                            v = jax.tree_util.tree_map(jnp.array, v)
-                            ctx.stats["donation_copies"] += 1
-                        donated[k] = v
-                    outs = fused_fn(donated, env)
-                    ctx.stats["donated_chunks"] += len(donated)
+        with span("mozart.drive", chunks=len(ranges)):
+            for i, (s, e) in enumerate(ranges):
+                resilience.maybe_fail("chunk",
+                                      f"stage {stage.id} chunk [{s},{e})")
+                env = chunk_env_for(stage, concrete, s, e, ctx.pedantic,
+                                    chunk_index=i, force_slice=donate)
+                if mode == "pipelined":
+                    run_chain(stage, env, jit_each=True)
+                    ctx.stats["calls"] += len(stage.nodes)
+                    outs = {p: env[("n", p)] for p in esc}
                 else:
-                    outs = fused_fn(env)
-                ctx.stats["calls"] += 1
-            for p, v in outs.items():
-                partials[p].append(v)
-            if ctx.log:
-                print(f"[mozart] stage {stage.id} chunk [{s},{e}) done")
-        mark_stream_consumed(stage, concrete, ctx, set(donate) - unsafe)
-        finish_stage(stage, partials, ranges, ctx)
+                    if donate:
+                        # Observable streams donate a defensive COPY — their
+                        # own chunk buffers must survive a later
+                        # Future.value.
+                        donated = {}
+                        for k in donate:
+                            v = env.pop(k)
+                            if k in unsafe:
+                                v = jax.tree_util.tree_map(jnp.array, v)
+                                ctx.stats["donation_copies"] += 1
+                            donated[k] = v
+                        outs = fused_fn(donated, env)
+                        ctx.stats["donated_chunks"] += len(donated)
+                    else:
+                        outs = fused_fn(env)
+                    ctx.stats["calls"] += 1
+                for p, v in outs.items():
+                    partials[p].append(v)
+        with span("mozart.merge"):
+            mark_stream_consumed(stage, concrete, ctx, set(donate) - unsafe)
+            finish_stage(stage, partials, ranges, ctx)
 
 
 @register_executor("pipelined")
@@ -291,23 +294,24 @@ def _build_scan_driver(stage: Stage, esc: tuple[int, ...],
         # donated argument: XLA reuses the dead (n_chunks, batch, …) buffer
         # for this stage's stacked outputs instead of allocating fresh ones —
         # the scan-driver rendering of the fused driver's chunk donation.
-        def driver_donate(donated: dict, stacked_inputs: dict, bcast_env: dict):
+        def mozart_scan_driver_donate(donated: dict, stacked_inputs: dict,
+                                      bcast_env: dict):
             note_trace()
             stacked_inputs = dict(stacked_inputs)
             stacked_inputs.update(donated)
             return jax.lax.map(lambda sv: chain_fn(sv, bcast_env),
                                stacked_inputs)
 
-        return jax.jit(driver_donate, donate_argnums=(0,))
+        return jax.jit(mozart_scan_driver_donate, donate_argnums=(0,))
 
-    def driver(stacked_inputs: dict, bcast_env: dict):
+    def mozart_scan_driver(stacked_inputs: dict, bcast_env: dict):
         # Broadcast values ride along as a real jit argument (not a closure
         # capture): the pinned executable must not bake one call's scalars
         # into the compiled program.
         note_trace()
         return jax.lax.map(lambda sv: chain_fn(sv, bcast_env), stacked_inputs)
 
-    return jax.jit(driver)
+    return jax.jit(mozart_scan_driver)
 
 
 @register_executor("scan")
@@ -416,16 +420,26 @@ class ScanExecutor(StageExecutor):
                         for i in range(n_chunks)]
                 if not main:
                     return None
-                return jax.tree_util.tree_map(
+                slabs = jax.tree_util.tree_map(
                     lambda *ls: jnp.stack(ls), *main)
+                count_layout(ctx, slabs, main if ax else ())
+                return slabs
 
             def stack_leaf(leaf):
                 lead = jnp.moveaxis(leaf, ax, 0) if ax else leaf
-                main = lead[:n_main].reshape((n_chunks, batch) + lead.shape[1:])
+                head = lead[:n_main]
+                main = head.reshape((n_chunks, batch) + lead.shape[1:])
+                # jax returns its argument where a move or slice is trivial:
+                # count the copies actually asked for.
+                count_layout(ctx, main, [x for x, src in
+                                         ((lead, leaf), (head, lead))
+                                         if x is not src])
                 return main
             return jax.tree_util.tree_map(stack_leaf, v)
 
-        stacked_inputs = {stage.ckey(key): stacked(key) for key in split_keys}
+        with span("mozart.layout"):
+            stacked_inputs = {stage.ckey(key): stacked(key)
+                              for key in split_keys}
         bcast_env = {stage.ckey(k): concrete[k] for k, si in stage.inputs.items()
                      if not si.split_type.splittable}
 
@@ -450,78 +464,89 @@ class ScanExecutor(StageExecutor):
                                        donate))
 
         consumed_keys: tuple = ()
-        if n_chunks:
-            resilience.maybe_fail("chunk", f"stage {stage.id} scan driver")
-            if donate:
-                key_of = {stage.ckey(k): k for k in stage.inputs}
-                donated = {}
-                for ck in donate:
-                    val = stacked_inputs.pop(ck)
-                    if ck in fresh_stacked:
-                        # Our own stack: the stream's chunk buffers survive
-                        # regardless — donate without copying or consuming.
-                        donated[ck] = val
-                    elif (ck in unsafe or not isinstance(
-                            concrete.get(key_of[ck]), ChunkStream)):
-                        # Observable carry pass-through, or a plain array
-                        # whose reshape may alias the producer's retained
-                        # result: donate a defensive copy.
-                        donated[ck] = jax.tree_util.tree_map(jnp.array, val)
-                        ctx.stats["donation_copies"] += 1
-                    else:
-                        donated[ck] = val        # dead carry: real donation
-                        consumed_keys += (ck,)
-                stacked_outs = driver(donated, stacked_inputs, bcast_env)
-                ctx.stats["donated_chunks"] += len(donated)
+        n_ran = n_chunks + (1 if n_main < n else 0)
+        with span("mozart.drive", chunks=n_ran):
+            if n_chunks:
+                resilience.maybe_fail("chunk",
+                                      f"stage {stage.id} scan driver")
+                if donate:
+                    key_of = {stage.ckey(k): k for k in stage.inputs}
+                    donated = {}
+                    for ck in donate:
+                        val = stacked_inputs.pop(ck)
+                        if ck in fresh_stacked:
+                            # Our own stack: the stream's chunk buffers
+                            # survive regardless — donate without copying or
+                            # consuming.
+                            donated[ck] = val
+                        elif (ck in unsafe or not isinstance(
+                                concrete.get(key_of[ck]), ChunkStream)):
+                            # Observable carry pass-through, or a plain array
+                            # whose reshape may alias the producer's retained
+                            # result: donate a defensive copy.
+                            donated[ck] = jax.tree_util.tree_map(jnp.array,
+                                                                 val)
+                            ctx.stats["donation_copies"] += 1
+                        else:
+                            donated[ck] = val    # dead carry: real donation
+                            consumed_keys += (ck,)
+                    stacked_outs = driver(donated, stacked_inputs, bcast_env)
+                    ctx.stats["donated_chunks"] += len(donated)
+                else:
+                    stacked_outs = driver(stacked_inputs, bcast_env)
             else:
-                stacked_outs = driver(stacked_inputs, bcast_env)
-        else:
-            stacked_outs = {p: None for p in esc}
-        ctx.stats["chunks"] += n_chunks + (1 if n_main < n else 0)
-        ctx.stats["calls"] += 1
+                stacked_outs = {p: None for p in esc}
+            ctx.stats["chunks"] += n_ran
+            ctx.stats["calls"] += 1
+
+            tail_env = None
+            if n_main < n:  # ragged tail
+                tail_env = chunk_env_for(stage, concrete, n_main, n,
+                                         ctx.pedantic, chunk_index=n_chunks)
+                run_chain(stage, tail_env, jit_each=False)
 
         # Which outputs stay in carry form (the handoff plan's decision).
         plan_ho = getattr(ctx, "_handoff", None)
         ho = plan_ho.get(stage.id) if plan_ho else None
         ranges = batch_ranges(n, batch)
-
-        tail_env = None
-        if n_main < n:  # ragged tail
-            tail_env = chunk_env_for(stage, concrete, n_main, n, ctx.pedantic,
-                                     chunk_index=n_chunks)
-            run_chain(stage, tail_env, jit_each=False)
-
-        partials: dict[int, list[Any]] = {}
-        for nid in stage.escaping:
-            p = stage.pos[nid]
-            t = stage.out_types[nid]
-            ax = split_axis_of(t)
-            node = next(nd for nd in stage.nodes if nd.id == nid)
-            tail_piece = tail_env[("n", p)] if tail_env is not None else None
-            if (ho is not None and p in ho.stream_out and ax is not None
-                    and n_chunks and len(ranges) > 1):
-                # Streamed output: keep the driver's carry layout — a scan
-                # consumer ingests it with zero copies, a chunk-loop consumer
-                # derives the chunk list lazily, and observation merges
-                # lazily via Future.value.
-                node.result = ChunkStream.from_stacked(
-                    stacked_outs[p], tail_piece, ranges, t, node.out_aval)
-                node.done = True
-                ctx.stats["streamed_outputs"] += 1
-                continue
-            pieces: list[Any] = []
-            if n_chunks:
-                so = stacked_outs[p]
-                if ax is not None:
-                    def unstack(l):
-                        flat = l.reshape((n_chunks * batch,) + l.shape[2:])
-                        return jnp.moveaxis(flat, 0, ax) if ax else flat
-                    pieces.append(jax.tree_util.tree_map(unstack, so))
-                else:  # ReduceSplit etc.: merge over the stacked leading dim
-                    pieces.extend(jax.tree_util.tree_map(lambda l: l[i], so)
-                                  for i in range(n_chunks))
-            if tail_piece is not None:
-                pieces.append(tail_piece)
-            partials[p] = pieces
-        mark_stream_consumed(stage, concrete, ctx, consumed_keys)
-        finish_stage(stage, partials, ctx=ctx)
+        with span("mozart.merge"):
+            partials: dict[int, list[Any]] = {}
+            for nid in stage.escaping:
+                p = stage.pos[nid]
+                t = stage.out_types[nid]
+                ax = split_axis_of(t)
+                node = next(nd for nd in stage.nodes if nd.id == nid)
+                tail_piece = (tail_env[("n", p)] if tail_env is not None
+                              else None)
+                if (ho is not None and p in ho.stream_out and ax is not None
+                        and n_chunks and len(ranges) > 1):
+                    # Streamed output: keep the driver's carry layout — a
+                    # scan consumer ingests it with zero copies, a chunk-loop
+                    # consumer derives the chunk list lazily, and observation
+                    # merges lazily via Future.value.
+                    node.result = ChunkStream.from_stacked(
+                        stacked_outs[p], tail_piece, ranges, t,
+                        node.out_aval)
+                    node.done = True
+                    ctx.stats["streamed_outputs"] += 1
+                    continue
+                pieces: list[Any] = []
+                if n_chunks:
+                    so = stacked_outs[p]
+                    if ax is not None:
+                        def unstack(l):
+                            flat = l.reshape((n_chunks * batch,) + l.shape[2:])
+                            out = jnp.moveaxis(flat, 0, ax) if ax else flat
+                            count_layout(ctx, flat, out if ax else ())
+                            return out
+                        pieces.append(jax.tree_util.tree_map(unstack, so))
+                    else:  # ReduceSplit etc.: merge over the stacked dim
+                        per_chunk = [jax.tree_util.tree_map(lambda l: l[i], so)
+                                     for i in range(n_chunks)]
+                        count_layout(ctx, per_chunk)
+                        pieces.extend(per_chunk)
+                if tail_piece is not None:
+                    pieces.append(tail_piece)
+                partials[p] = pieces
+            mark_stream_consumed(stage, concrete, ctx, consumed_keys)
+            finish_stage(stage, partials, ctx=ctx)

@@ -53,6 +53,7 @@ from repro.core.stage_exec import (
     register_executor,
     stage_num_elements,
 )
+from repro.core.trace import count_layout, span
 from repro.kernels import split_pipeline as sp
 
 
@@ -229,7 +230,7 @@ class _KernelLaunch:
 def _build_pallas_driver(stage: Stage, chain_fn: Callable, n_split: int,
                          out_kinds: list[tuple[str, str]], out_dtypes: list,
                          block: int, vmem_limit: int) -> _KernelLaunch:
-    def driver(donated: dict, rest: dict, bcast_vals, n: int):
+    def mozart_pallas_driver(donated: dict, rest: dict, bcast_vals, n: int):
         # Launch buffers arrive prebuilt in the padded (rows, 128) layout
         # (position-keyed so donated and retained buffers reassemble in
         # split-key order); the true length ``n`` is a static argument —
@@ -242,7 +243,8 @@ def _build_pallas_driver(stage: Stage, chain_fn: Callable, n_split: int,
             vmem_limit)
 
     return _KernelLaunch(
-        jax.jit(driver, static_argnums=(3,), donate_argnums=(0,)),
+        jax.jit(mozart_pallas_driver, static_argnums=(3,),
+                donate_argnums=(0,)),
         f"stage {stage.id} split-pipeline kernel, block {block}")
 
 
@@ -263,9 +265,12 @@ def _to_launch_layout(v: Any, n: int, block: int, stage: Stage, ck: tuple,
     did (cross-evaluation arrival can flap call-to-call — inside-jit
     padding would retrace on every flap, breaking the warm zero-retrace
     invariant), and only an argument buffer can be DONATED (a padded
-    intermediate built inside the jit has no donation story)."""
+    intermediate built inside the jit has no donation story).  Every copy
+    issued here counts in ``ctx.stats["layout_bytes"]``."""
     if not isinstance(v, ChunkStream):
-        return sp.pad_to_layout(v, n, block), sp._round_up(n, block) > n
+        buf = sp.pad_to_layout(v, n, block)
+        count_layout(ctx, buf, buf)        # the pad, then its reshape
+        return buf, sp._round_up(n, block) > n
 
     grid_ranges = batch_ranges(n, block)
     # scan→pallas: a carry-form stream whose batch IS the block re-views
@@ -277,8 +282,11 @@ def _to_launch_layout(v: Any, n: int, block: int, stage: Stage, ck: tuple,
         if v.tail is not None:
             pad = block - int(v.tail.shape[0])
             rows.append(jnp.pad(v.tail, (0, pad)).reshape(1, block))
+            count_layout(ctx, rows[-1], rows[-1])
         buf = rows[0] if len(rows) == 1 else jnp.concatenate(rows, axis=0)
-        return buf.reshape(-1, sp.LANES), True
+        out = buf.reshape(-1, sp.LANES)
+        count_layout(ctx, out, buf if len(rows) > 1 else ())
+        return out, True
 
     chunks, ranges = v.chunks, v.ranges
     if ranges != grid_ranges:
@@ -295,7 +303,9 @@ def _to_launch_layout(v: Any, n: int, block: int, stage: Stage, ck: tuple,
     if ragged:
         rows.append(jnp.pad(chunks[-1], (0, block - sizes[-1]))
                     .reshape(-1, sp.LANES))
+    count_layout(ctx, rows, rows)          # stack or pad, then reshape
     buf = rows[0] if len(rows) == 1 else jnp.concatenate(rows, axis=0)
+    count_layout(ctx, buf if len(rows) > 1 else ())
     return buf, True
 
 
@@ -378,26 +388,34 @@ def try_execute_stage_pallas(stage: Stage, concrete: dict[tuple, Any], ctx,
 
     donated: dict[int, Any] = {}
     rest: dict[int, Any] = {}
-    for i, k in enumerate(split_keys):
-        buf, fresh = _to_launch_layout(concrete[k], n, block, stage,
-                                       stage.ckey(k), ctx)
-        if i not in donate_pos:
-            rest[i] = buf
-        elif fresh:
-            donated[i] = buf           # our own assembly: donation is free
-        else:
-            # A whole array whose launch view may alias the producer's
-            # retained result: donate a copy.
-            donated[i] = jnp.array(buf)
-            ctx.stats["donation_copies"] += 1
+    with span("mozart.layout"):
+        for i, k in enumerate(split_keys):
+            buf, fresh = _to_launch_layout(concrete[k], n, block, stage,
+                                           stage.ckey(k), ctx)
+            if i not in donate_pos:
+                rest[i] = buf
+            elif fresh:
+                donated[i] = buf       # our own assembly: donation is free
+            else:
+                # A whole array whose launch view may alias the producer's
+                # retained result: donate a copy.
+                donated[i] = jnp.array(buf)
+                ctx.stats["donation_copies"] += 1
     if donated:
         ctx.stats["donated_chunks"] += len(donated)
 
-    outs = driver(donated, rest, [concrete[k] for k in bcast_keys], n)
-    results = sp.unpad_outputs(outs, out_kinds, n)
-    for nid, res in zip(escape_ids, results):
-        node = next(nd for nd in stage.nodes if nd.id == nid)
-        node.result = res
+    grid = sp._round_up(n, block) // block
+    with span("mozart.drive", chunks=grid):
+        outs = driver(donated, rest, [concrete[k] for k in bcast_keys], n)
+    ctx.stats["chunks"] += grid
+    with span("mozart.merge"):
+        results = sp.unpad_outputs(outs, out_kinds, n)
+        for o, r, (kind, _) in zip(outs, results, out_kinds):
+            if kind == "concat":           # the reshape, then the slice
+                count_layout(ctx, o, r if r.size < o.size else ())
+        for nid, res in zip(escape_ids, results):
+            node = next(nd for nd in stage.nodes if nd.id == nid)
+            node.result = res
     for node in stage.nodes:
         node.done = True
     ctx.stats["pallas_stages"] += 1

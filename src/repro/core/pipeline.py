@@ -43,6 +43,7 @@ import jax
 from repro.core import resilience
 from repro.core.future import Future
 from repro.core.runtime import MozartContext, _stack
+from repro.core.trace import span
 
 #: compile() runs at most this many passes while converging to the pinned
 #: steady state (plan -> measure/tune -> compile real shapes -> quiescent).
@@ -167,7 +168,7 @@ class Pipeline:
             _stack().append(ctx)
             try:
                 ctx.stats["graph_captures"] += 1
-                out = self.fn(*args, **kwargs)
+                out = self._capture(ctx, args, kwargs)
             finally:
                 _stack().pop()
             pending = ctx.graph.pending()
@@ -248,24 +249,26 @@ class Pipeline:
         captures, zero fingerprints, zero planner calls, zero retraces."""
         self._require_fn()
         from repro.core import stage_exec
-        with self._lock:
+        with span("mozart.call") as call_span, self._lock:
             ctx = self.ctx
             before = dict(ctx.stats)
             traces_before = stage_exec.trace_count()
             result = _NO_FAST
             if self._fast is not None:
                 result = self._fast_call(args, kwargs)
+            call_span.set_metadata(fast=result is not _NO_FAST)
             if result is _NO_FAST:
                 _stack().append(ctx)
                 try:
                     ctx.stats["graph_captures"] += 1
-                    out = self.fn(*args, **kwargs)
+                    out = self._capture(ctx, args, kwargs)
                     if (self.arg_transparent and self.compiled
                             and self._fast is None):
                         result = self._build_fast(out, args, kwargs)
                     if result is _NO_FAST:
                         ctx.evaluate()
-                        result = _force(out)
+                        with span("mozart.force"):
+                            result = _force(out)
                 finally:
                     _stack().pop()
                 ctx.graph.prune()
@@ -274,6 +277,14 @@ class Pipeline:
             delta["jit_traces"] = stage_exec.trace_count() - traces_before
             self._last_call_stats = delta
             return result
+
+    def _capture(self, ctx, args, kwargs):
+        """Run ``fn`` lazily: each annotated call registers a node."""
+        registered = ctx.stats["registered"]
+        with span("mozart.capture") as s:
+            out = self.fn(*args, **kwargs)
+            s.set_metadata(nodes=ctx.stats["registered"] - registered)
+        return out
 
     def call_with_stats(self, *args, **kwargs):
         """``(result, stats_delta)`` for one call, atomically.
@@ -357,7 +368,8 @@ class Pipeline:
             treedef=treedef, leaf_specs=[_leaf_spec(l) for l in leaves],
             alias_sig=_alias_sig(leaves), node_bindings=node_bindings,
             input_bindings=input_bindings)
-        return _force(out)
+        with span("mozart.force"):
+            return _force(out)
 
     def _fast_call(self, args, kwargs):
         """Re-execute the retained node set with this call's arrays rebound.
@@ -402,7 +414,8 @@ class Pipeline:
         finally:
             ctx._plan_entry, ctx._handoff = prev
         ctx.stats["fast_path_calls"] += 1
-        return _force(f.out)
+        with span("mozart.force"):
+            return _force(f.out)
 
     # -- introspection -------------------------------------------------------
     @property

@@ -63,6 +63,7 @@ from repro import hardware
 from repro.core import split_types as st
 from repro.core.graph import DataflowGraph, Node, NodeRef
 from repro.core.planner import Stage, StageInput, _value_key, plan
+from repro.core.trace import span
 
 _MAX_ENTRIES = 256
 
@@ -649,9 +650,22 @@ def lookup_or_plan(pending: list[Node], graph: DataflowGraph,
     downstream — fingerprint, planner, handoff analysis, templates — sees the
     rewritten graph.  The rewrite is cheap, deterministic pure Python: warm
     calls re-run it per capture and land on the rewritten graph's cache key,
-    replaying the optimized plan with zero planner calls and zero retraces."""
+    replaying the optimized plan with zero planner calls and zero retraces.
+
+    The whole lookup is the ``mozart.plan`` span, ``hit`` set on a cache
+    hit."""
+    hits = ctx.stats["plan_cache_hits"]
+    with span("mozart.plan") as plan_span:
+        found = _lookup_or_plan(pending, graph, ctx)
+        plan_span.set_metadata(hit=ctx.stats["plan_cache_hits"] > hits)
+    return found
+
+
+def _lookup_or_plan(pending: list[Node], graph: DataflowGraph,
+                    ctx) -> tuple[list[Stage], PlanEntry | None]:
     from repro.core import rewrite as rewrite_mod
-    rw = rewrite_mod.apply(pending, graph, ctx)
+    with span("mozart.rewrite"):
+        rw = rewrite_mod.apply(pending, graph, ctx)
     pending = rw.pending
     ctx._last_rewrites = rw.records
     max_nodes = None if ctx.pipeline else 1
@@ -661,7 +675,8 @@ def lookup_or_plan(pending: list[Node], graph: DataflowGraph,
         ctx.stats["planner_calls"] += 1
         return plan(pending, graph, max_stage_nodes=max_nodes), None
 
-    key = fingerprint(pending, graph, ctx)
+    with span("mozart.fingerprint"):
+        key = fingerprint(pending, graph, ctx)
     if key is None:
         with _lock:
             stats["uncacheable"] += 1

@@ -27,6 +27,7 @@ from repro.core.graph import DataflowGraph, NodeRef
 from repro.core.planner import plan
 from repro.core.resilience import inject_faults  # noqa: F401  (mozart.inject_faults)
 from repro.core.stage_exec import BoundaryCounters, counter_scope, get_executor
+from repro.core.trace import span
 
 
 class MozartContext:
@@ -39,7 +40,6 @@ class MozartContext:
         lazy: bool = True,
         pedantic: bool = False,
         batch_elements: int | None = None,
-        log: bool = False,
         inner_executor: str = "fused",
         pipeline: bool = True,
         plan_cache: bool = True,
@@ -55,7 +55,6 @@ class MozartContext:
         self.lazy = lazy
         self.pedantic = pedantic
         self.batch_elements = batch_elements
-        self.log = log
         self.inner_executor = inner_executor    # per-shard strategy for "sharded"
         self.pipeline = pipeline                 # False: Table-4 "-pipe" ablation
         self.plan_cache = plan_cache             # reuse plans across evaluations
@@ -117,36 +116,36 @@ class MozartContext:
         pending = self.graph.pending()
         if not pending:
             return
-        from repro.core.plan_cache import lookup_or_plan
-        stages, entry = lookup_or_plan(pending, self.graph, self)
-        self.stats["evaluations"] += 1
-        if self.log:
-            for s in stages:
-                names = ",".join(n.fn.name for n in s.nodes)
-                print(f"[mozart] stage {s.id}: [{names}] inputs="
-                      f"{[str(si.split_type) for si in s.inputs.values()]}")
-        # Handoff decisions: replayed from the cache entry (zero analysis on
-        # warm calls); uncacheable pipelines analyze fresh per evaluation.
         from repro.core.handoff import resolve_decisions
-        ho = resolve_decisions(self, entry, stages)
-        # Save/restore (not clear): a dynamic node forcing a Future of this
-        # same session re-enters evaluate(), and the outer plan's entry must
-        # survive the nested call.
-        prev_entry, prev_ho = self._plan_entry, self._handoff
-        self._plan_entry = entry
-        self._handoff = ho
-        try:
-            # Dispatch PER STAGE: under ``executor="auto"`` each stage is
-            # scored and routed independently (cost_model.AutoExecutor).
-            # Trace/boundary events attribute to THIS context's counters
-            # (plus the process-global aggregate) for the duration.
-            # ``resilience.run_stage`` arms the degradation ladder: a failing
-            # executor is quarantined and the stage completes on a lower rung.
-            with counter_scope(self.counters):
-                for s in stages:
-                    resilience.run_stage(self.executor, s, self.graph, self)
-        finally:
-            self._plan_entry, self._handoff = prev_entry, prev_ho
+        from repro.core.plan_cache import lookup_or_plan
+        with span("mozart.evaluate") as evaluate_span:
+            stages, entry = lookup_or_plan(pending, self.graph, self)
+            self.stats["evaluations"] += 1
+            evaluate_span.set_metadata(stages=len(stages))
+            # Handoff decisions: replayed from the cache entry (zero analysis
+            # on warm calls); uncacheable pipelines analyze fresh per
+            # evaluation.
+            ho = resolve_decisions(self, entry, stages)
+            # Save/restore (not clear): a dynamic node forcing a Future of
+            # this same session re-enters evaluate(), and the outer plan's
+            # entry must survive the nested call.
+            prev_entry, prev_ho = self._plan_entry, self._handoff
+            self._plan_entry = entry
+            self._handoff = ho
+            try:
+                # Dispatch PER STAGE: under ``executor="auto"`` each stage is
+                # scored and routed independently (cost_model.AutoExecutor).
+                # Trace/boundary events attribute to THIS context's counters
+                # (plus the process-global aggregate) for the duration.
+                # ``resilience.run_stage`` arms the degradation ladder: a
+                # failing executor is quarantined and the stage completes on
+                # a lower rung.
+                with counter_scope(self.counters):
+                    for s in stages:
+                        resilience.run_stage(self.executor, s, self.graph,
+                                             self)
+            finally:
+                self._plan_entry, self._handoff = prev_entry, prev_ho
         self.graph.prune()
 
     def last_plan(self):

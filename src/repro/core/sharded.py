@@ -100,7 +100,7 @@ def _build_sharded_driver(stage: Stage, mesh, axes, in_specs, out_specs,
     plan = chain_plan(stage)
     axis_name = axes if len(axes) > 1 else axes[0]
 
-    def local_fn(*vals):
+    def mozart_sharded_driver(*vals):
         note_trace()
         env = dict(zip(in_ckeys, vals))
         # Per-device fast-memory chunk loop over the local shard.
@@ -130,7 +130,7 @@ def _build_sharded_driver(stage: Stage, mesh, axes, in_specs, out_specs,
 
     return jax.jit(
         jax.shard_map(
-            local_fn,
+            mozart_sharded_driver,
             mesh=mesh,
             in_specs=tuple(in_specs),
             out_specs=tuple(out_specs),

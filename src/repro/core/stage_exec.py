@@ -40,6 +40,7 @@ from repro.core import resilience
 from repro.core import split_types as st
 from repro.core.graph import DataflowGraph, Node, NodeRef
 from repro.core.planner import Stage, _count_of_type, _value_key
+from repro.core.trace import span
 
 
 class PedanticError(RuntimeError):
@@ -1160,16 +1161,24 @@ class StageExecutor:
 
     # -- template method ----------------------------------------------------
     def run(self, stage: Stage, graph: DataflowGraph, ctx) -> None:
-        concrete = resolve_stage_inputs(stage, graph, ctx, self.stream_capable,
-                                        shard_ok=self.shard_capable)
-        entry = getattr(ctx, "_plan_entry", None)
-        if self._should_tune(stage, ctx, entry):
-            # Sampled tuning re-slices inputs at arbitrary offsets: a one-time
-            # event, so streams are merged rather than complicating sampling.
-            concrete = materialize_inputs(stage, concrete, ctx)
-            self._tune(stage, concrete, ctx, entry)
-        else:
-            self.execute(stage, concrete, ctx)
+        laid = ctx.stats.get("layout_bytes", 0)
+        with span("mozart.stage", stage=stage.id,
+                  executor=self.name) as stage_span:
+            with span("mozart.inputs"):
+                concrete = resolve_stage_inputs(
+                    stage, graph, ctx, self.stream_capable,
+                    shard_ok=self.shard_capable)
+            entry = getattr(ctx, "_plan_entry", None)
+            if self._should_tune(stage, ctx, entry):
+                # Sampled tuning re-slices inputs at arbitrary offsets: a
+                # one-time event, so streams are merged rather than
+                # complicating sampling.
+                concrete = materialize_inputs(stage, concrete, ctx)
+                self._tune(stage, concrete, ctx, entry)
+            else:
+                self.execute(stage, concrete, ctx)
+            stage_span.set_metadata(
+                layout_bytes=ctx.stats.get("layout_bytes", 0) - laid)
         ctx.stats["stages"] += 1
         for node in stage.nodes:
             node.done = True
