@@ -15,9 +15,11 @@ Strategies registered here (see ``core/stage_exec.py`` for the registry):
                      chunk at a time.
 * ``"fused"``      — beyond-paper: the whole per-chunk chain is traced into
                      ONE jitted function (still driven chunk-by-chunk).
-* ``"scan"``       — beyond-paper: equal-size chunks are stacked and the
-                     fused chain is driven by ``lax.map`` so the chunk loop
-                     itself compiles to a single streaming XLA loop.
+* ``"scan"``       — beyond-paper: the chunk loop itself compiles to one
+                     XLA ``fori_loop`` that reads each chunk in place from
+                     the flat inputs and writes its results into whole-size
+                     outputs in the loop carry; the ragged tail runs in the
+                     same program.
 
 ``"sharded"`` (mesh scale-out) and ``"pallas"`` (TPU split-pipeline kernel)
 live in ``core/sharded.py`` / ``core/pallas_exec.py``.
@@ -30,12 +32,15 @@ reuse the same compiled executable — zero retraces (``note_trace``).
 
 from __future__ import annotations
 
+import math
 from typing import Any, Callable
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 
 from repro.core import resilience
+from repro.core import split_types as st
 from repro.core.graph import NodeRef
 from repro.core.planner import Stage
 from repro.core.trace import count_layout, span
@@ -43,7 +48,9 @@ from repro.core.stage_exec import (
     ChunkStream,
     PedanticError,
     StageExecutor,
+    batch_is_explicit,
     batch_ranges,
+    candidate_batches,
     chain_plan,
     chunk_env_for,
     donatable_input_keys,
@@ -266,180 +273,282 @@ class FusedExecutor(ChunkedExecutor):
     mode = "fused"
 
 
+def split_tile(shape: tuple, axis: int) -> int:
+    """Elements along ``axis`` in one tile of the TPU's default layout, so
+    a chunk starting on a multiple of it reads and writes whole tiles.
+
+    Read from layouts the v5e compiler assigns (``tests/test_tpu_compile.py``
+    checks them): a 1-D array is tiled by 1024 elements
+    (``f32[n]{0:T(1024)}``; 16- and 8-bit types add sub-tiles inside the
+    same 1024), the last two axes of a larger one by 8 rows of 128 lanes
+    (``{…,1,0:T(8,128)}``), and the axes above those are not tiled."""
+    rank = len(shape)
+    if rank == 1:
+        return 1024
+    if rank >= 2 and axis == rank - 1:
+        return 128
+    if rank >= 2 and axis == rank - 2:
+        return 8
+    return 1
+
+
+def _stage_tile(stage: Stage, concrete: dict[tuple, Any]) -> int:
+    """The tile every split input and split output of the stage agrees on
+    (the least common multiple of their ``split_tile``)."""
+    tile = 1
+    values = []
+    for key, si in stage.inputs.items():
+        v = concrete[key]
+        values.append((split_axis_of(si.split_type),
+                       v.aval if isinstance(v, ChunkStream) else v))
+    for node in stage.nodes:
+        if node.id in stage.escaping:
+            values.append((split_axis_of(stage.out_types[node.id]),
+                           node.out_aval))
+    for ax, v in values:
+        if ax is None:
+            continue
+        for leaf in jax.tree.leaves(v):
+            if len(getattr(leaf, "shape", ())) > ax:
+                tile = math.lcm(tile, split_tile(leaf.shape, ax))
+    return tile
+
+
+#: lanes of a TPU vector register: the minor tile of every layout.
+_LANES = 128
+
+
+def _aligned(batch: int, n: int, tile: int) -> int:
+    """``batch`` rounded down to a whole number of tiles; a single chunk
+    (``batch >= n``) and a batch under one tile stay as they are."""
+    return batch if batch >= n or batch < tile else batch // tile * tile
+
+
 def _build_scan_driver(stage: Stage, esc: tuple[int, ...],
                        split_axes: dict[tuple, int],
-                       out_axes: dict[int, int | None],
+                       out_axes: dict[int, int | None], batch: int,
                        donate: tuple = ()) -> Callable:
-    plan = chain_plan(stage)
+    """One compiled program for the whole stage: a ``fori_loop`` over the
+    main chunks and one more call of the chain on the ragged tail.
 
-    def chain_fn(split_vals: dict, bcast_env: dict):
+    Split inputs arrive flat, in their own shapes; chunk ``i`` is a
+    ``dynamic_slice`` of each at ``i * batch`` (a 1-D value sliced as rows
+    of 128 lanes), which XLA fuses into the chain.  Split outputs are allocated whole in the loop carry and each
+    chunk's result goes in with an in-place ``dynamic_update_slice``, so
+    the driver's outputs are the merged values; a reduction output (the
+    one kind without a split axis) folds into the carry in chunk order
+    (``ReduceSplit.merge``).  ``n`` is read from the inputs' shapes, so one
+    pinned driver serves every length at this batch."""
+    plan = chain_plan(stage)
+    reduce_types = {stage.pos[nid]: t for nid, t in stage.out_types.items()
+                    if nid in stage.escaping and isinstance(t, st.ReduceSplit)}
+
+    def chain(vals: dict, bcast_env: dict) -> dict:
         env = dict(bcast_env)
-        for key, v in split_vals.items():
-            ax = split_axes[key]
-            env[key] = jax.tree_util.tree_map(
-                lambda l: jnp.moveaxis(l, 0, ax) if ax else l, v)
+        env.update(vals)
         run_plan(plan, env)
-        outs = {}
+        return {p: env[("n", p)] for p in esc}
+
+    def as_rows(l) -> bool:
+        # A 1-D value is laid out as rows of 128 lanes, 8 rows a tile, so a
+        # reshape to rows is free; sliced as rows, a chunk of whole tiles
+        # moves no lane.  On a v5e, a Black–Scholes loop over 2^27 elements
+        # ran 1.3-2.3x faster this way than sliced along the 1-D axis.
+        return (l.ndim == 1 and l.shape[0] % _LANES == 0
+                and batch % (8 * _LANES) == 0)
+
+    def cut(l, ax: int, start, size: int):
+        if as_rows(l):
+            rows = lax.dynamic_slice_in_dim(l.reshape(-1, _LANES),
+                                            start // _LANES, size // _LANES)
+            return rows.reshape(size)
+        return lax.dynamic_slice_in_dim(l, start, size, ax)
+
+    def paste(buf, v, ax: int, start):
+        if as_rows(buf):
+            rows = lax.dynamic_update_slice_in_dim(
+                buf.reshape(-1, _LANES), v.reshape(-1, _LANES),
+                start // _LANES, 0)
+            return rows.reshape(buf.shape)
+        return lax.dynamic_update_slice_in_dim(buf, v, start, ax)
+
+    def window(vals: dict, start, size: int) -> dict:
+        return {k: jax.tree.map(
+                    lambda l, ax=split_axes[k]: cut(l, ax, start, size), v)
+                for k, v in vals.items()}
+
+    def put(bufs: dict, outs: dict, i, start) -> dict:
+        """Chunk ``i``'s results, written at ``start`` or folded in."""
+        new = {}
+        for p, buf in bufs.items():
+            ax, val = out_axes[p], outs[p]
+            if ax is None:
+                new[p] = jnp.where(i == 0, val,
+                                   reduce_types[p].merge([buf, val]))
+            else:
+                new[p] = jax.tree.map(
+                    lambda b, v, ax=ax: paste(b, v, ax, start), buf, val)
+        return new
+
+    def carry_aliases(split_vals: dict, part: dict, n: int) -> dict:
+        """Output position -> donated input key whose buffer becomes that
+        output's carry, paired as jax pairs donated arguments with results
+        (outputs in order, each taking the first donated input of its shape
+        and dtype).  An input read from its own carry chunk by chunk is
+        overwritten in place only after it was read, so XLA needs no copy;
+        a pair that splits along different axes gets no carry."""
+        free = [k for k in sorted(donate) if hasattr(split_vals[k], "shape")]
+        alias = {}
         for p in esc:
+            ax, leaf = out_axes[p], part[p]
+            if ax is None or not hasattr(leaf, "shape"):
+                continue
+            shape = leaf.shape[:ax] + (n,) + leaf.shape[ax + 1:]
+            for k in free:
+                v = split_vals[k]
+                if v.shape == shape and v.dtype == leaf.dtype:
+                    free.remove(k)
+                    if split_axes[k] == ax:
+                        alias[p] = k
+                    break
+        return alias
+
+    def drive(split_vals: dict, bcast_env: dict) -> dict:
+        note_trace()
+        k0 = next(iter(split_vals))
+        n = jax.tree.leaves(split_vals[k0])[0].shape[split_axes[k0]]
+        n_chunks = n // batch
+        part = jax.eval_shape(chain, window(split_vals, 0, batch), bcast_env)
+        alias = carry_aliases(split_vals, part, n)
+        read_from = {k: p for p, k in alias.items()}
+
+        def empty(p, l):
             ax = out_axes[p]
-            o = env[("n", p)]
-            if ax is not None:
-                o = jax.tree_util.tree_map(
-                    lambda l: jnp.moveaxis(l, ax, 0) if ax else l, o)
-            outs[p] = o
-        return outs
+            if ax is None:
+                return jnp.zeros(l.shape, l.dtype)
+            return lax.empty(l.shape[:ax] + (n,) + l.shape[ax + 1:], l.dtype)
+
+        def sources(bufs: dict) -> dict:
+            return {k: bufs[read_from[k]] if k in read_from else v
+                    for k, v in split_vals.items()}
+
+        bufs = lax.fori_loop(
+            0, n_chunks,
+            lambda i, bufs: put(bufs, chain(window(sources(bufs), i * batch,
+                                                   batch), bcast_env),
+                                i, i * batch),
+            {p: split_vals[alias[p]] if p in alias
+             else jax.tree.map(lambda l, p=p: empty(p, l), part[p])
+             for p in esc})
+        n_main = n_chunks * batch
+        if n_main < n:
+            tail = chain(window(sources(bufs), n_main, n - n_main), bcast_env)
+            bufs = put(bufs, tail, n_chunks, n_main)
+        return bufs
 
     if donate:
-        # Stacked carry buffers that die at this stage arrive as a separate
-        # donated argument: XLA reuses the dead (n_chunks, batch, …) buffer
-        # for this stage's stacked outputs instead of allocating fresh ones —
-        # the scan-driver rendering of the fused driver's chunk donation.
-        def mozart_scan_driver_donate(donated: dict, stacked_inputs: dict,
+        # Dead split inputs arrive as a separate donated argument; the
+        # pairing above lets XLA write an output into a donated input's
+        # buffer as it goes.
+        def mozart_scan_driver_donate(donated: dict, split_vals: dict,
                                       bcast_env: dict):
-            note_trace()
-            stacked_inputs = dict(stacked_inputs)
-            stacked_inputs.update(donated)
-            return jax.lax.map(lambda sv: chain_fn(sv, bcast_env),
-                               stacked_inputs)
+            return drive({**split_vals, **donated}, bcast_env)
 
         return jax.jit(mozart_scan_driver_donate, donate_argnums=(0,))
 
-    def mozart_scan_driver(stacked_inputs: dict, bcast_env: dict):
+    def mozart_scan_driver(split_vals: dict, bcast_env: dict):
         # Broadcast values ride along as a real jit argument (not a closure
         # capture): the pinned executable must not bake one call's scalars
         # into the compiled program.
-        note_trace()
-        return jax.lax.map(lambda sv: chain_fn(sv, bcast_env), stacked_inputs)
+        return drive(split_vals, bcast_env)
 
     return jax.jit(mozart_scan_driver)
 
 
 @register_executor("scan")
 class ScanExecutor(StageExecutor):
-    """Stack equal-size chunks and drive the fused chain with ``lax.map``.
+    """The whole chunk loop as one compiled XLA program over flat values.
 
-    The chunk loop compiles into a single XLA while-loop whose body touches
-    one fast-memory-sized batch at a time — the TPU-native rendering of the
-    paper's driver loop.  The ragged tail chunk is handled separately.
+    The driver (``_build_scan_driver``) takes each split input whole, reads
+    chunk ``i`` in place with a ``dynamic_slice`` and writes each result
+    into whole-size outputs held in the loop carry, so nothing is stacked,
+    sliced or reshaped outside it; the ragged tail runs inside the same
+    program.  The batch from the §5.2 estimate or the tuner is rounded down
+    to the stage's layout tile (``split_tile``) so every chunk starts on a
+    tile boundary; an explicit ``batch_elements`` is kept as given.
 
-    Chunk handoff: an incoming ``ChunkStream`` is stacked DIRECTLY into the
-    driver's carry layout — the producer's own stacked carry passes through
-    untouched when the grids agree (scan→scan is zero-copy), a chunk list
-    stacks in one gather (equal-grid fast path), and disagreeing grids
-    convert through ``SplitType.rechunk`` first — ``materialize()`` is never
-    called on ingest.  Streamed outputs keep the carry layout
-    (``ChunkStream.from_stacked``), and dying stacked inputs are donated to
-    the driver under the same structural (plan-derived) donate-key rules as
-    the fused driver, so pinned variants never flap and warm calls stay
-    zero-retrace.
+    Chunk handoff: the driver's outputs are already merged, so a streamed
+    output is a ``ChunkStream.from_merged``; a scan or pallas consumer reads
+    it whole with zero copies, and an incoming chunk-list stream is
+    concatenated once (a counted layout copy).  Dead split inputs are
+    donated to the driver under the same structural (plan-derived)
+    donate-key rules as the fused driver, so pinned variants never flap and
+    warm calls stay zero-retrace.  Stages the driver cannot run (dynamic
+    functions, empty splits, an input or a non-reduction output with no
+    split axis) go to ``pipelined`` or ``fused``, counted in
+    ``ctx.stats["scan_fallbacks"]``.
     """
 
     tunable = True
     stream_capable = True
 
-    #: same grid-adoption slack as the chunk-loop drivers: a producer grid
-    #: whose chunks are at most this factor over the §5.2 estimate is
-    #: adopted as the scan batch (zero copies); beyond it the stream is
-    #: re-gridded to protect the fast-memory budget.
-    GRID_SLACK = 2.0
+    def _batch_and_tile(self, stage: Stage, concrete: dict[tuple, Any], ctx,
+                        n: int) -> tuple[int, int]:
+        batch = super().choose_batch(stage, concrete, ctx, n)
+        if batch_is_explicit(ctx):
+            return batch, 1
+        tile = _stage_tile(stage, concrete)
+        aligned = _aligned(batch, n, tile)
+        return aligned, (tile if aligned < n and tile <= batch else 1)
 
-    def _ingest_streams(self, stage: Stage, concrete: dict[tuple, Any], ctx,
-                        n: int, batch: int) -> tuple[dict[tuple, Any], int]:
-        """Align stream inputs onto ONE regular grid; returns the batch.
+    def choose_batch(self, stage: Stage, concrete: dict[tuple, Any], ctx,
+                     n: int) -> int:
+        return self._batch_and_tile(stage, concrete, ctx, n)[0]
 
-        The scan layout needs equal-size main chunks + one ragged tail,
-        which is exactly the shape of a ``batch_ranges`` grid: a stream
-        whose grid already is one (within ``GRID_SLACK`` of the estimate)
-        fixes the batch; anything else rechunks — at most one copy."""
-        streams = [(k, v) for k, v in concrete.items()
-                   if isinstance(v, ChunkStream)]
-        if not streams or n <= 0:
-            return concrete, batch
-        base = streams[0][1]
-        ub = base.uniform_batch()
-        if (ub and ub <= batch * self.GRID_SLACK
-                and base.ranges == batch_ranges(n, ub)):
-            batch = ub                     # adopt the producer's grid as-is
-        grid = batch_ranges(n, batch)
-        out = dict(concrete)
-        for k, v in streams:
-            if v.ranges != grid:
-                chunks, copied = v.split_type.rechunk(v.chunks, v.ranges, grid)
-                out[k] = ChunkStream(chunks, grid, v.split_type, v.aval)
-                note_materialized(copied, kind="rechunk",
-                                  where=f"stage {stage.id} input {stage.ckey(k)}")
-                ctx.stats["handoff_rechunks"] += 1
-        return out, batch
+    def tuning_candidates(self, stage: Stage, concrete: dict[tuple, Any], ctx,
+                          est: int, n: int) -> list[int]:
+        tile = _stage_tile(stage, concrete)
+        return sorted({_aligned(c, n, tile)
+                       for c in candidate_batches(est, n)})
+
+    def _fallback(self, name: str, stage: Stage, concrete: dict[tuple, Any],
+                  ctx) -> None:
+        ctx.stats["scan_fallbacks"] += 1
+        get_executor(name).execute(stage, concrete, ctx)
 
     def execute(self, stage: Stage, concrete: dict[tuple, Any], ctx) -> None:
         if has_dynamic(stage):
-            return get_executor("pipelined").execute(stage, concrete, ctx)
-
+            return self._fallback("pipelined", stage, concrete, ctx)
         n = effective_elements(ctx, stage_num_elements(stage, concrete, ctx.pedantic))
-        if n == 0:
-            # Empty split: the stacked driver has no chunks to map over; the
-            # fused driver runs one degenerate zero-size chunk instead (and
-            # handles any zero-element stream input itself).
-            return get_executor("fused").execute(stage, concrete, ctx)
-        batch = self.choose_batch(stage, concrete, ctx, n)
-        concrete, batch = self._ingest_streams(stage, concrete, ctx, n, batch)
-        n_main = (n // batch) * batch
-        n_chunks = n_main // batch
-
-        # Outputs whose split axis we know get stacked; everything else falls
-        # back to the fused python driver.
-        for nid in stage.escaping:
-            if split_axis_of(stage.out_types[nid]) is None and stage.out_types[nid].splittable:
-                return get_executor("fused").execute(stage, concrete, ctx)
-
+        # An empty split has no chunk to loop over: the fused driver runs
+        # one degenerate zero-size chunk.  An input or output with no split
+        # axis (bar a reduction) cannot be sliced or written in place.
         split_keys = [k for k, si in stage.inputs.items() if si.split_type.splittable]
-        if not split_keys or any(
-            split_axis_of(stage.inputs[k].split_type) is None for k in split_keys
-        ):
-            return get_executor("fused").execute(stage, concrete, ctx)
+        if (n == 0 or not split_keys
+                or any(split_axis_of(stage.inputs[k].split_type) is None
+                       for k in split_keys)
+                or any(split_axis_of(t) is None
+                       and not isinstance(t, st.ReduceSplit)
+                       for nid, t in stage.out_types.items()
+                       if nid in stage.escaping)):
+            return self._fallback("fused", stage, concrete, ctx)
+        batch, tile = self._batch_and_tile(stage, concrete, ctx, n)
 
-        fresh_stacked: set[tuple] = set()    # ckeys whose stacked buffer is ours
-
-        def stacked(key):
-            si = stage.inputs[key]
-            ax = split_axis_of(si.split_type)
-            v = concrete[key]
-            if isinstance(v, ChunkStream):
-                if (v.stacked is not None and v._chunks is None
-                        and v.uniform_batch() == batch):
-                    # scan→scan: the producer's carry layout IS this stage's
-                    # stacked input — zero copies, zero dispatches.
-                    return v.stacked
-                # Equal-grid fast path: stack the chunk list straight into
-                # the carry layout (one gather — the merge+reshape round
-                # trip is gone).
-                fresh_stacked.add(stage.ckey(key))
-                main = [jax.tree_util.tree_map(
-                            lambda l: jnp.moveaxis(l, ax, 0) if ax else l,
-                            v.chunk(i))
-                        for i in range(n_chunks)]
-                if not main:
-                    return None
-                slabs = jax.tree_util.tree_map(
-                    lambda *ls: jnp.stack(ls), *main)
-                count_layout(ctx, slabs, main if ax else ())
-                return slabs
-
-            def stack_leaf(leaf):
-                lead = jnp.moveaxis(leaf, ax, 0) if ax else leaf
-                head = lead[:n_main]
-                main = head.reshape((n_chunks, batch) + lead.shape[1:])
-                # jax returns its argument where a move or slice is trivial:
-                # count the copies actually asked for.
-                count_layout(ctx, main, [x for x, src in
-                                         ((lead, leaf), (head, lead))
-                                         if x is not src])
-                return main
-            return jax.tree_util.tree_map(stack_leaf, v)
-
+        fresh: set[tuple] = set()       # ckeys whose flat value we built
+        split_vals: dict[tuple, Any] = {}
         with span("mozart.layout"):
-            stacked_inputs = {stage.ckey(key): stacked(key)
-                              for key in split_keys}
+            for key in split_keys:
+                v = concrete[key]
+                if isinstance(v, ChunkStream):
+                    if v.merged is not None:
+                        v = v.merged         # already whole: zero copies
+                    else:
+                        chunks = v.chunks    # one concatenation
+                        v = v.split_type.merge(chunks)
+                        if len(chunks) > 1:
+                            count_layout(ctx, v)
+                            fresh.add(stage.ckey(key))
+                split_vals[stage.ckey(key)] = v
         bcast_env = {stage.ckey(k): concrete[k] for k, si in stage.inputs.items()
                      if not si.split_type.splittable}
 
@@ -449,104 +558,60 @@ class ScanExecutor(StageExecutor):
         out_axes = {stage.pos[nid]: split_axis_of(stage.out_types[nid])
                     for nid in stage.escaping}
 
-        # Donation: structural key set shared with the fused driver.  The
-        # donated value is always the STACKED buffer; whether it may be the
-        # stream's own storage is a runtime question (a fresh stack we built
-        # is always safe; a passed-through carry or a plain reshaped array
-        # donates a defensive copy unless provably dead).
+        # Donation: structural key set shared with the fused driver.  A flat
+        # value we concatenated is always safe to donate; a dead stream's
+        # held value is donated for real (and the stream marked consumed);
+        # an observable stream or a plain array, which may be a producer's
+        # retained result, donates a defensive copy.
         donate = tuple(k for k in donatable_input_keys(stage, ctx)
-                       if k in stacked_inputs) if n_chunks else ()
+                       if k in split_vals)
         unsafe = undonatable_stream_keys(stage, concrete, ctx, donate) \
             if donate else set()
         driver = pinned_jit(
             stage, ctx, "scan", (esc, batch, donate),
             lambda: _build_scan_driver(stage, esc, split_axes, out_axes,
-                                       donate))
+                                       batch, donate))
 
         consumed_keys: tuple = ()
-        n_ran = n_chunks + (1 if n_main < n else 0)
-        with span("mozart.drive", chunks=n_ran):
-            if n_chunks:
-                resilience.maybe_fail("chunk",
-                                      f"stage {stage.id} scan driver")
-                if donate:
-                    key_of = {stage.ckey(k): k for k in stage.inputs}
-                    donated = {}
-                    for ck in donate:
-                        val = stacked_inputs.pop(ck)
-                        if ck in fresh_stacked:
-                            # Our own stack: the stream's chunk buffers
-                            # survive regardless — donate without copying or
-                            # consuming.
-                            donated[ck] = val
-                        elif (ck in unsafe or not isinstance(
-                                concrete.get(key_of[ck]), ChunkStream)):
-                            # Observable carry pass-through, or a plain array
-                            # whose reshape may alias the producer's retained
-                            # result: donate a defensive copy.
-                            donated[ck] = jax.tree_util.tree_map(jnp.array,
-                                                                 val)
-                            ctx.stats["donation_copies"] += 1
-                        else:
-                            donated[ck] = val    # dead carry: real donation
-                            consumed_keys += (ck,)
-                    stacked_outs = driver(donated, stacked_inputs, bcast_env)
-                    ctx.stats["donated_chunks"] += len(donated)
-                else:
-                    stacked_outs = driver(stacked_inputs, bcast_env)
+        ranges = batch_ranges(n, batch)
+        with span("mozart.drive", chunks=len(ranges), tile=tile):
+            resilience.maybe_fail("chunk", f"stage {stage.id} scan driver")
+            if donate:
+                key_of = {stage.ckey(k): k for k in stage.inputs}
+                donated = {}
+                for ck in donate:
+                    val = split_vals.pop(ck)
+                    if ck in fresh:
+                        donated[ck] = val
+                    elif (ck in unsafe or not isinstance(
+                            concrete.get(key_of[ck]), ChunkStream)):
+                        donated[ck] = jax.tree.map(jnp.array, val)
+                        ctx.stats["donation_copies"] += 1
+                    else:
+                        donated[ck] = val
+                        consumed_keys += (ck,)
+                outs = driver(donated, split_vals, bcast_env)
+                ctx.stats["donated_chunks"] += len(donated)
             else:
-                stacked_outs = {p: None for p in esc}
-            ctx.stats["chunks"] += n_ran
+                outs = driver(split_vals, bcast_env)
+            ctx.stats["chunks"] += len(ranges)
             ctx.stats["calls"] += 1
 
-            tail_env = None
-            if n_main < n:  # ragged tail
-                tail_env = chunk_env_for(stage, concrete, n_main, n,
-                                         ctx.pedantic, chunk_index=n_chunks)
-                run_chain(stage, tail_env, jit_each=False)
-
-        # Which outputs stay in carry form (the handoff plan's decision).
+        # Which outputs stay streams (the handoff plan's decision).
         plan_ho = getattr(ctx, "_handoff", None)
         ho = plan_ho.get(stage.id) if plan_ho else None
-        ranges = batch_ranges(n, batch)
         with span("mozart.merge"):
             partials: dict[int, list[Any]] = {}
             for nid in stage.escaping:
                 p = stage.pos[nid]
                 t = stage.out_types[nid]
-                ax = split_axis_of(t)
-                node = next(nd for nd in stage.nodes if nd.id == nid)
-                tail_piece = (tail_env[("n", p)] if tail_env is not None
-                              else None)
-                if (ho is not None and p in ho.stream_out and ax is not None
-                        and n_chunks and len(ranges) > 1):
-                    # Streamed output: keep the driver's carry layout — a
-                    # scan consumer ingests it with zero copies, a chunk-loop
-                    # consumer derives the chunk list lazily, and observation
-                    # merges lazily via Future.value.
-                    node.result = ChunkStream.from_stacked(
-                        stacked_outs[p], tail_piece, ranges, t,
-                        node.out_aval)
-                    node.done = True
+                if (ho is not None and p in ho.stream_out
+                        and out_axes[p] is not None and len(ranges) > 1):
+                    node = next(nd for nd in stage.nodes if nd.id == nid)
+                    node.result = ChunkStream.from_merged(outs[p], ranges, t,
+                                                          node.out_aval)
                     ctx.stats["streamed_outputs"] += 1
-                    continue
-                pieces: list[Any] = []
-                if n_chunks:
-                    so = stacked_outs[p]
-                    if ax is not None:
-                        def unstack(l):
-                            flat = l.reshape((n_chunks * batch,) + l.shape[2:])
-                            out = jnp.moveaxis(flat, 0, ax) if ax else flat
-                            count_layout(ctx, flat, out if ax else ())
-                            return out
-                        pieces.append(jax.tree_util.tree_map(unstack, so))
-                    else:  # ReduceSplit etc.: merge over the stacked dim
-                        per_chunk = [jax.tree_util.tree_map(lambda l: l[i], so)
-                                     for i in range(n_chunks)]
-                        count_layout(ctx, per_chunk)
-                        pieces.extend(per_chunk)
-                if tail_piece is not None:
-                    pieces.append(tail_piece)
-                partials[p] = pieces
+                else:
+                    partials[p] = [outs[p]]
             mark_stream_consumed(stage, concrete, ctx, consumed_keys)
             finish_stage(stage, partials, ctx=ctx)

@@ -96,9 +96,11 @@ class PallasExecutor(StageExecutor):
     """Lower eligible elementwise stages onto the split-pipeline TPU kernel;
     anything the kernel cannot express falls back to the fused driver.
 
-    Chunk handoff: an incoming ``ChunkStream`` is stacked DIRECTLY into the
-    kernel's padded ``(rows, 128)`` launch layout (equal-grid fast path;
-    ``rechunk`` for disagreeing grids) instead of being merged and re-padded;
+    Chunk handoff: an incoming chunk-list ``ChunkStream`` is stacked
+    DIRECTLY into the kernel's padded ``(rows, 128)`` launch layout
+    (equal-grid fast path; ``rechunk`` for disagreeing grids) instead of
+    being merged and re-padded, and a stream that holds its whole value (a
+    scan output) is laid out as a plain array;
     launch buffers the stage's handoff plan proves dead here are donated to
     the jitted launch driver under the same structural donate-key rules as
     the fused/scan drivers."""
@@ -254,10 +256,11 @@ def _to_launch_layout(v: Any, n: int, block: int, stage: Stage, ck: tuple,
 
     Returns ``(buffer, fresh)`` — ``fresh`` means the buffer was assembled
     here (stack/pad/reshape copies) and may be donated without endangering
-    anyone else's storage; a stream's buffer always is.  A handed-off
-    ``ChunkStream`` stacks its chunk list straight into the layout
-    (equal-grid fast path; ``rechunk`` for disagreeing grids) —
-    ``materialize()`` is never called.
+    anyone else's storage; a chunk list's buffer always is.  A stream that
+    already holds its whole value (a scan driver's output, scan→pallas)
+    takes the plain-array path; a chunk-list ``ChunkStream`` stacks its
+    chunks straight into the layout (equal-grid fast path; ``rechunk`` for
+    disagreeing grids) — ``materialize()`` is never called.
 
     Building the buffer EAGERLY (outside the pinned driver) costs a few
     extra dispatches per call, and is deliberate twice over: the driver's
@@ -267,27 +270,14 @@ def _to_launch_layout(v: Any, n: int, block: int, stage: Stage, ck: tuple,
     invariant), and only an argument buffer can be DONATED (a padded
     intermediate built inside the jit has no donation story).  Every copy
     issued here counts in ``ctx.stats["layout_bytes"]``."""
+    if isinstance(v, ChunkStream) and v.merged is not None:
+        v = v.merged
     if not isinstance(v, ChunkStream):
         buf = sp.pad_to_layout(v, n, block)
         count_layout(ctx, buf, buf)        # the pad, then its reshape
         return buf, sp._round_up(n, block) > n
 
     grid_ranges = batch_ranges(n, block)
-    # scan→pallas: a carry-form stream whose batch IS the block re-views
-    # its (k, block) main buffer as rows of 128 lanes — no chunk list.
-    if (v.stacked is not None and v._chunks is None
-            and v.uniform_batch() == block
-            and isinstance(v.stacked, jax.Array) and v.stacked.ndim == 2):
-        rows = [v.stacked]
-        if v.tail is not None:
-            pad = block - int(v.tail.shape[0])
-            rows.append(jnp.pad(v.tail, (0, pad)).reshape(1, block))
-            count_layout(ctx, rows[-1], rows[-1])
-        buf = rows[0] if len(rows) == 1 else jnp.concatenate(rows, axis=0)
-        out = buf.reshape(-1, sp.LANES)
-        count_layout(ctx, out, buf if len(rows) > 1 else ())
-        return out, True
-
     chunks, ranges = v.chunks, v.ranges
     if ranges != grid_ranges:
         chunks, copied = v.split_type.rechunk(chunks, ranges, grid_ranges)

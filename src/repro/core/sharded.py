@@ -147,7 +147,7 @@ def _ingest_streams(stage: Stage, concrete: dict[tuple, Any], ctx, mesh,
     Three paths, in order of preference: a SHARDED-form stream whose layout
     already matches the target (same Sharding, shard-grid ranges) passes its
     device-resident global array through untouched (zero interior bytes, no
-    all-gather); a chunk-list/stacked stream is regrouped onto the shard
+    all-gather); a chunk-list or merged stream is regrouped onto the shard
     grid (``rechunk`` at most once — counted) and ``device_put`` per shard
     into one global array (device placement is inherent to sharding, like
     splitting an external input, so it is NOT counted as interior traffic);

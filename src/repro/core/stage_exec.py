@@ -422,13 +422,13 @@ class ChunkStream:
     ``Future`` forces it, or a stream-incapable executor resolves it);
     ``materialize`` caches the merged value so it is paid at most once.
 
-    Three storage forms share this class.  The chunk-LIST form holds one
-    buffer per grid range (the chunk-loop executors' native output).  The
-    STACKED form (``from_stacked``) holds the ``scan`` driver's carry layout
-    directly — one ``(n_chunks, batch, …)`` leaf per pytree leaf plus an
-    optional ragged ``tail`` chunk — so a scan→scan boundary hands the carry
-    buffer over with zero slicing; a chunk-loop consumer derives the chunk
-    list lazily (paying, and counting, one slice pass).  The SHARDED form
+    Two storage forms share this class.  The chunk-LIST form holds one
+    buffer per grid range (the chunk-loop executors' native output); a
+    stream built by ``from_merged`` (the ``scan`` driver's output, which is
+    already the whole value) is the same form with its merge done: a scan
+    or pallas consumer reads the value whole with zero copies,
+    ``materialize`` is free, and a chunk-loop consumer slices its chunk
+    views from it (paying, and counting, one slice pass).  The SHARDED form
     (``from_sharded``) holds the sharded driver's device-resident global
     ``jax.Array`` plus its ``Sharding`` — one grid range per mesh shard — so
     a sharded→sharded boundary passes the global array straight through
@@ -437,7 +437,7 @@ class ChunkStream:
     """
 
     __slots__ = ("_chunks", "ranges", "split_type", "aval", "_merged",
-                 "consumed", "donor", "stacked", "tail", "sharded", "sharding")
+                 "consumed", "donor", "sharded", "sharding")
 
     def __init__(self, chunks: list | None, ranges: list,
                  split_type: st.SplitType, aval: Any):
@@ -448,22 +448,16 @@ class ChunkStream:
         self._merged = None
         self.consumed = False              # chunk buffers donated to a driver
         self.donor = ""                    # "stage N input K" that donated them
-        self.stacked = None                # (n_chunks, batch, …) carry layout
-        self.tail = None                   # ragged tail chunk (chunk-shaped)
         self.sharded = None                # device-resident global jax.Array
         self.sharding = None               # its jax.sharding.Sharding
 
     @classmethod
-    def from_stacked(cls, stacked: Any, tail: Any, ranges: list,
-                     split_type: st.SplitType, aval: Any) -> "ChunkStream":
-        """Wrap a scan driver's carry layout without unstacking it.
-
-        ``stacked`` leaves are ``(n_chunks, batch, …)`` with the split axis
-        already moved to position 1 (the scan stacking convention); ``tail``
-        is the ragged last chunk in normal chunk form, or None."""
+    def from_merged(cls, merged: Any, ranges: list,
+                    split_type: st.SplitType, aval: Any) -> "ChunkStream":
+        """Wrap a whole value (the scan driver's output) with the grid it
+        was computed on; no chunk list is held until one is asked for."""
         s = cls(None, ranges, split_type, aval)
-        s.stacked = stacked
-        s.tail = tail
+        s._merged = merged
         return s
 
     @classmethod
@@ -511,35 +505,29 @@ class ChunkStream:
             lambda a: jnp.zeros(a.shape, a.dtype), self.aval)
 
     @property
-    def chunks(self) -> list:
-        """The chunk list, deriving (and counting) it from stacked storage.
+    def merged(self) -> Any:
+        """The whole value when this stream already holds it (a scan
+        driver's output, or a stream merged once), else None: free to read."""
+        return self._merged
 
-        A stacked stream only pays this slice pass when a chunk-loop
-        consumer actually iterates it; a scan consumer uses ``stacked``
-        directly and the derivation never happens.  A sharded stream derives
-        zero-copy per-shard views (``addressable_shards`` in grid order) —
-        the buffers stay committed to their devices, so only shard-aware
-        consumers may iterate them."""
+    @property
+    def chunks(self) -> list:
+        """The chunk list, deriving (and counting) it from a held value.
+
+        A ``from_merged`` stream only pays this slice pass when a chunk-loop
+        consumer actually iterates it; a scan or pallas consumer reads the
+        value whole and the derivation never happens.  A sharded stream
+        derives zero-copy per-shard views (``addressable_shards`` in grid
+        order) — the buffers stay committed to their devices, so only
+        shard-aware consumers may iterate them."""
         if self._chunks is None:
-            ax = self._axis()
             if self.sharded is not None:
+                ax = self._axis()
                 shards = sorted(self.sharded.addressable_shards,
                                 key=lambda sh: sh.index[ax].start or 0)
                 self._chunks = [sh.data for sh in shards]
                 return self._chunks
-            k = len(self.ranges) - (1 if self.tail is not None else 0)
-
-            def unstack_one(i):
-                return jax.tree_util.tree_map(
-                    lambda l: jnp.moveaxis(l[i], 0, ax), self.stacked)
-
-            derived = [unstack_one(i) for i in range(k)]
-            if self.tail is not None:
-                derived.append(self.tail)
-            self._chunks = derived
-            nb = sum(_value_nbytes(c) for c in derived)
-            note_materialized(nb, kind="unstack",
-                              where=f"stream n={self.n} {self.split_type}")
+            self._chunks = [self.chunk(i) for i in range(len(self.ranges))]
         return self._chunks
 
     def chunk(self, i: int) -> Any:
@@ -549,15 +537,10 @@ class ChunkStream:
         buffer at all; they resolve to an empty value built from the aval."""
         if self._chunks is None and self.sharded is not None:
             return self.chunks[i]          # zero-copy per-shard views
-        if self._chunks is None and self.stacked is not None:
-            k = len(self.ranges) - (1 if self.tail is not None else 0)
-            if i >= k and self.tail is not None:
-                return self.tail
-            ax = self._axis()
-            piece = jax.tree_util.tree_map(
-                lambda l: jnp.moveaxis(l[i], 0, ax), self.stacked)
+        if self._chunks is None and self._merged is not None:
             s, e = self.ranges[i]
-            note_materialized(_value_nbytes(piece), kind="unstack",
+            piece = self.split_type.split(self._merged, s, e)
+            note_materialized(_value_nbytes(piece), kind="resplit",
                               where=f"stream chunk [{s},{e})")
             return piece
         if not self._chunks and self.n == 0:
@@ -582,11 +565,12 @@ class ChunkStream:
         ``terminal=True`` marks the merge as observation of a pipeline
         output (``Future.value``) — accounted under ``bytes_terminal`` so
         the interior-boundary gate never charges observation costs."""
+        if self.consumed:
+            # A held merged value may itself be the donated buffer.
+            raise RuntimeError(
+                DONATED_MERGE_ERROR
+                + f" [donated at {self.donor or 'unknown stage/edge'}]")
         if self._merged is None:
-            if self.consumed:
-                raise RuntimeError(
-                    DONATED_MERGE_ERROR
-                    + f" [donated at {self.donor or 'unknown stage/edge'}]")
             if self.sharded is not None:
                 # The global array IS the merged value; returning it is free
                 # NOW, but a non-mesh consumer forces XLA to gather/reshard
@@ -597,39 +581,24 @@ class ChunkStream:
                                   terminal=terminal, kind="gather",
                                   where=f"stream n={self.n} {self.split_type}")
                 return self._merged
-            if self.stacked is not None and self._chunks is None:
-                self._merged = self._merge_stacked()
-            elif not self._chunks:
+            if not self._chunks:
                 # Zero-chunk stream (empty pipeline): merge([]) would crash
                 # in the library's concat; the aval names the empty result.
                 self._merged = self._empty_value()
             else:
                 self._merged = self.split_type.merge(self._chunks)
-            if (self._chunks is None and self.stacked is not None) \
-                    or len(self._chunks or ()) > 1:
+            if len(self._chunks or ()) > 1:
                 note_materialized(_value_nbytes(self._merged),
                                   terminal=terminal,
                                   kind="materialize",
                                   where=f"stream n={self.n} {self.split_type}")
         return self._merged
 
-    def _merge_stacked(self) -> Any:
-        ax = self._axis()
-
-        def flat(l):
-            body = l.reshape((l.shape[0] * l.shape[1],) + l.shape[2:])
-            return jnp.moveaxis(body, 0, ax)
-
-        main = jax.tree_util.tree_map(flat, self.stacked)
-        if self.tail is None:
-            return main
-        return self.split_type.merge([main, self.tail])
-
     def __repr__(self) -> str:
         if self.sharded is not None:
             form = f"sharded×{len(self.ranges)}"
-        elif self._chunks is None and self.stacked is not None:
-            form = "stacked"
+        elif self._chunks is None and self._merged is not None:
+            form = "merged"
         else:
             form = f"{len(self._chunks or ())} chunks"
         return f"ChunkStream({form}, n={self.n}, {self.split_type})"
@@ -840,7 +809,7 @@ def adapt_stream(v: "ChunkStream", consumer: st.SplitType) -> "ChunkStream | Non
     mismatch); the caller materializes instead, which is always correct."""
     if not isinstance(v.split_type, st.ConcatSplit):
         return None
-    if v._chunks is None:              # stacked ConcatSplit streams don't exist
+    if v._chunks is None:              # merged ConcatSplit streams don't exist
         return None
     if isinstance(consumer, st.ArraySplit) and consumer.shape:
         ax, total = consumer.axis, consumer.shape[consumer.axis]
@@ -1073,7 +1042,7 @@ def mark_stream_consumed(stage: Stage, concrete: dict[tuple, Any], ctx,
                 t.donor = t.donor or donor
                 if sanitize:
                     t._chunks = _PoisonedChunks(t.donor)
-                    t.stacked = t.tail = t.sharded = None
+                    t._merged = t.sharded = None
             if sanitize:
                 note_materialized(0, kind="donate", where=donor)
 
@@ -1106,13 +1075,20 @@ def _block_stage_outputs(stage: Stage) -> None:
                 r = node.result
                 if isinstance(r, ChunkStream):
                     # Raw storage, never the derived chunk list: blocking must
-                    # not charge an unstack pass to the boundary counters.
-                    r = [x for x in (r._chunks, r.stacked, r.tail, r.sharded)
+                    # not charge a slice pass to the boundary counters.
+                    r = [x for x in (r._chunks, r._merged, r.sharded)
                          if x is not None]
                 jax.block_until_ready(r)
             except resilience.PROBE_ERRORS as e:
                 # non-array results (tables, corpora): nothing async
                 resilience.note_swallowed("block_stage_outputs", e)
+
+
+def batch_is_explicit(ctx) -> bool:
+    """Whether the batch is set by hand (``batch_elements``) or by the tuner
+    timing one candidate (``_batch_override``): then it is used exactly."""
+    return (getattr(ctx, "_batch_override", None) is not None
+            or bool(ctx.batch_elements))
 
 
 def candidate_batches(est: int, n: int) -> list[int]:
@@ -1213,8 +1189,7 @@ class StageExecutor:
             and entry is not None
             and entry.hits > 0                      # first execution of a CACHED plan
             and getattr(ctx, "autotune", True)
-            and not ctx.batch_elements
-            and getattr(ctx, "_batch_override", None) is None
+            and not batch_is_explicit(ctx)
             and stage.id not in entry.tuned_batch
             # dynamic (call_raw) functions may carry side effects and their
             # runtime is value-dependent: never re-execute them to time them

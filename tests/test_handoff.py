@@ -4,9 +4,9 @@ Covers: the SplitType ``can_handoff``/``rechunk`` protocol (including the
 misaligned-grid property test and the ConcatSplit→ArraySplit rule);
 differential parity (handoff on vs off) across every registered executor
 and across ElementSplit/ReduceSplit/broadcast/axis-mismatch edges with
-empty and odd-size inputs; ``scan``/``pallas`` stream ingest (carry-layout
-stacking, padded-launch-buffer stacking, zero interior bytes, zero warm
-retraces); interior-vs-terminal boundary-byte accounting; zero-chunk
+empty and odd-size inputs; ``scan``/``pallas`` stream ingest (flat
+values read whole, padded-launch-buffer stacking, zero interior bytes, zero
+warm retraces); interior-vs-terminal boundary-byte accounting; zero-chunk
 stream hardening; chunk-buffer donation safety (plan-time veto of
 observable producers + the pinned runtime backstop); and
 ``MOZART_PLAN_CACHE`` round trips asserting recorded decisions — including
@@ -330,8 +330,8 @@ class TestBoundaryTraffic:
 
     def test_scan_ingests_fused_stream(self):
         """`scan` is a stream ingester now: a chunk-list stream from the
-        fused driver stacks straight into the carry layout — no
-        materialize on the boundary."""
+        fused driver is concatenated once into the flat value the driver
+        reads (a layout copy) — no materialize on the boundary."""
         x = jnp.linspace(0., 1., self.N, dtype=jnp.float32)
         plan_cache.clear()
         with mozart.session(executor="fused", batch_elements=self.BATCH) as ctx:
@@ -339,9 +339,11 @@ class TestBoundaryTraffic:
             mozart.evaluate()            # `a` streams (pure output, fused)
             assert isinstance(ctx.graph.nodes[a._node.id].result, ChunkStream)
             mozart.configure(executor="scan")
+            laid = ctx.stats.get("layout_bytes", 0)
             out = np.asarray(anp.exp(a))
         assert ctx.stats.get("stream_materialized", 0) == 0
         assert ctx.stats["stream_ingests"] >= 1
+        assert ctx.stats["layout_bytes"] - laid == self.N * 4
         want = np.exp((np.linspace(0., 1., self.N, dtype=np.float32) + 1) * 0.5)
         np.testing.assert_allclose(out, want, rtol=2e-5)
 
@@ -502,8 +504,9 @@ def test_handoff_decisions_replay_from_persisted_cache(tmp_path):
 
 class TestScanPallasIngest:
     """Every executor's interior boundary hits zero, not just the chunk
-    loops: `scan` stacks incoming streams into its carry layout, `pallas`
-    stacks them into the padded launch buffer."""
+    loops: `scan` reads a scan producer's flat output whole and
+    concatenates a chunk list once, `pallas` stacks streams into the padded
+    launch buffer."""
 
     N, BATCH = 50_000, 8192
 
@@ -539,13 +542,14 @@ class TestScanPallasIngest:
         # dead carries donate for real — no defensive copies on this chain
         assert ctx.stats["donated_chunks"] > 0
         assert ctx.stats.get("donation_copies", 0) == 0
-        # observation of the final output is TERMINAL, never interior
-        assert stage_exec.bytes_terminal() == self.N * 4
+        # the driver's outputs are already whole: observing the final one
+        # merges nothing, terminal or interior
+        assert stage_exec.bytes_terminal() == 0
 
     def test_scan_carry_passthrough_is_stacked(self):
-        """A scan stage's streamed output keeps the driver's carry layout
-        (ChunkStream.from_stacked) — a scan consumer ingests it without ever
-        deriving the chunk list."""
+        """A scan stage's streamed output is the driver's flat output with
+        its merge already done (ChunkStream.from_merged): a scan consumer
+        reads it whole — no chunk list, no layout copy, no boundary bytes."""
         x = jnp.linspace(0., 1., self.N, dtype=jnp.float32)
         plan_cache.clear()
         with mozart.session(executor="scan", batch_elements=self.BATCH) as ctx:
@@ -553,8 +557,15 @@ class TestScanPallasIngest:
             mozart.evaluate()
             res = ctx.graph.nodes[a._node.id].result
             assert isinstance(res, ChunkStream)
-            assert res.stacked is not None and res._chunks is None
+            assert res.merged is not None and res._chunks is None
+            assert res.merged.shape == (self.N,)
+            laid = ctx.stats.get("layout_bytes", 0)
+            stage_exec.reset_materialized()
             out = np.asarray(anp.exp(a))
+            assert ctx.stats.get("layout_bytes", 0) == laid
+            assert stage_exec.bytes_interior() == 0
+            assert res._chunks is None       # never sliced into chunks
+            assert ctx.stats["stream_ingests"] == 1
         want = np.exp((np.asarray(x) + 1) * 0.5)
         np.testing.assert_allclose(out, want, rtol=2e-5)
 

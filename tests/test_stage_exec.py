@@ -445,6 +445,105 @@ def test_future_exposes_split_type():
 
 
 # ---------------------------------------------------------------------------
+# The scan driver over flat values
+# ---------------------------------------------------------------------------
+
+
+def _eager_and_scan(fn, *args, **session):
+    with mozart.session(executor="eager"):
+        want = [np.asarray(v, np.float32) for v in fn(*args)]
+    with mozart.session(executor="scan", **session) as ctx:
+        got = [np.asarray(v, np.float32) for v in fn(*args)]
+    return want, got, ctx
+
+
+class TestScanFlat:
+    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                             ids=["f32", "bf16"])
+    @pytest.mark.parametrize("n", [4096, 5000, 4480],
+                             ids=["even", "ragged", "ragged_rows"])
+    def test_matches_eager_with_reduction_output(self, n, dtype):
+        """A chain with an elementwise and a sum output: the sum folds in
+        the loop carry, the tail runs in the same program.  4096 and 4480
+        are whole rows of 128 lanes, sliced as rows; 5000 is not."""
+        x = (jnp.arange(n, dtype=jnp.float32) / n).astype(dtype)
+        y = jnp.ones(n, dtype)
+        want, got, ctx = _eager_and_scan(quickstart, x, y,
+                                         batch_elements=1024)
+        tol = 2e-5 if dtype == jnp.float32 else 2e-2
+        np.testing.assert_allclose(got[0], want[0], rtol=tol, atol=1e-6)
+        np.testing.assert_allclose(got[1], want[1], rtol=tol)
+        assert ctx.stats["chunks"] == -(-n // 1024)
+        assert ctx.stats.get("scan_fallbacks", 0) == 0
+
+    @pytest.mark.parametrize("rows", [96, 100], ids=["even", "ragged"])
+    @pytest.mark.parametrize("split_axis", [0, 1])
+    def test_matches_eager_split_along_either_axis_of_2d(self, split_axis,
+                                                         rows):
+        """normalize_axis(m, axis) splits along the other axis."""
+        shape = (rows, 16) if split_axis == 0 else (16, rows)
+        m = jnp.arange(rows * 16, dtype=jnp.float32).reshape(shape) % 7.0
+        want, got, ctx = _eager_and_scan(
+            lambda v: (anp.normalize_axis(v, axis=1 - split_axis),), m,
+            batch_elements=8)
+        np.testing.assert_allclose(got[0], want[0], rtol=1e-4, atol=1e-5)
+        assert ctx.stats["chunks"] == -(-rows // 8)
+        assert ctx.stats.get("layout_bytes", 0) == 0
+
+    def test_plain_inputs_issue_no_layout_copy(self):
+        x = jnp.linspace(0.0, 1.0, 5000, dtype=jnp.float32)
+        y = jnp.ones(5000, jnp.float32)
+        _, _, ctx = _eager_and_scan(quickstart, x, y, batch_elements=1000)
+        assert ctx.stats["chunks"] == 5
+        assert ctx.stats.get("layout_bytes", 0) == 0
+
+    def test_estimated_batch_is_a_whole_number_of_tiles(self):
+        """The §5.2 estimate and every tuner candidate round down to the
+        1-D tile (1024 elements); an explicit batch is kept exactly."""
+        from repro.core import executor
+        ex = get_executor("scan")
+        n = 30_000
+        x = jnp.linspace(0.0, 1.0, n, dtype=jnp.float32)
+        y = jnp.ones(n, jnp.float32)
+        with mozart.session(executor="scan", chip=TINY_CHIP) as ctx:
+            c, s = quickstart(x, y)
+            (stage,) = ctx.last_plan()
+            np.asarray(c)
+        concrete = {k: si.value for k, si in stage.inputs.items()}
+        est = ex.estimate_batch(stage, concrete, ctx, n)
+        batch = ex.choose_batch(stage, concrete, ctx, n)
+        assert executor._stage_tile(stage, concrete) == 1024
+        assert est % 1024 and batch == est // 1024 * 1024
+        cands = ex.tuning_candidates(stage, concrete, ctx, est, n)
+        assert cands and all(c % 1024 == 0 for c in cands)
+        with mozart.session(executor="scan", batch_elements=1000) as ctx2:
+            assert ex.choose_batch(stage, concrete, ctx2, n) == 1000
+
+    def test_warm_call_retraces_nothing(self):
+        from repro.core import stage_exec
+        x = jnp.linspace(0.0, 1.0, 5000, dtype=jnp.float32)
+        y = jnp.ones(5000, jnp.float32)
+        p = mozart.pipeline(quickstart, executor="scan", batch_elements=1000)
+        p.lower(x, y).compile()
+        p(x, y)
+        t0 = stage_exec.trace_count()
+        (c, s), delta = p.call_with_stats(x, y)
+        np.asarray(c)
+        assert stage_exec.trace_count() == t0
+        assert delta.get("exec_builds", 0) == 0
+        assert delta["chunks"] == 5
+
+    def test_fallbacks_are_counted(self):
+        """An empty split has no chunk to loop over: it goes to ``fused``,
+        and the count says so."""
+        x = jnp.zeros((0,), jnp.float32)
+        with mozart.session(executor="scan") as ctx:
+            out = np.asarray(anp.multiply(anp.exp(x), 0.5))
+        assert out.shape == (0,)
+        assert ctx.stats["scan_fallbacks"] == 1
+
+
+# ---------------------------------------------------------------------------
 # Pallas block-shape-aware tuning (ROADMAP satellite)
 # ---------------------------------------------------------------------------
 

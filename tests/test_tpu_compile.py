@@ -12,10 +12,14 @@ and reduce outputs, at the blocks the executor picks for Black–Scholes
 (2^27 options) and data cleaning (2^28 values) on ``TPU_V5E`` — the §5.2
 estimate and the largest block the tuner may try — and the decline rule
 (``unlowerable_primitives``) checked against the compiler for every
-elementwise op of the annotated NumPy library.
+elementwise op of the annotated NumPy library.  The ``scan`` driver for
+the Black–Scholes stage at 2^27, at the executor's batches, compiles to
+in-place chunk reads and writes on flat values, and ``split_tile`` agrees
+with the layouts the compiler assigns.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -24,12 +28,12 @@ from jax.sharding import SingleDeviceSharding
 
 from benchmarks import workloads as w
 from repro import hardware
-from repro.core import mozart, plan_cache
+from repro.core import executor, mozart, plan_cache
 from repro.core import split_types as st
 from repro.core import annotated_numpy as anp
 from repro.core.pallas_exec import _block_cap, _effective_block, _make_chain_fn
 from repro.core.plan_cache import lookup_or_plan
-from repro.core.stage_exec import get_executor
+from repro.core.stage_exec import get_executor, split_axis_of
 from repro.kernels import split_pipeline as sp
 
 
@@ -217,3 +221,144 @@ def test_lowerable_set_has_lowering_rules():
     rules = {p.name for p in lowering.lowering_rules[core.KernelType.TC]}
     assert sp.LOWERABLE_PRIMITIVES <= rules, sp.LOWERABLE_PRIMITIVES - rules
 
+
+
+# ---------------------------------------------------------------------------
+# The scan driver reads and writes flat values in whole tiles
+# ---------------------------------------------------------------------------
+
+def _computations(hlo: str) -> dict:
+    """``{computation: ({instruction: (type, op, operands, attrs)}, root)}``
+    from a compiled module's text; the entry computation is ``"ENTRY"``."""
+    comps, cur = {}, None
+    for line in hlo.splitlines():
+        head = re.match(r"(ENTRY )?%(\S+) \(.*\{$", line)
+        if head:
+            cur = "ENTRY" if head.group(1) else head.group(2)
+            comps[cur] = ({}, None)
+            continue
+        m = re.match(r"\s*(ROOT )?%(\S+) = (.*?) ([a-z][a-z0-9-]*)\(([^)]*)\)"
+                     r"(.*)$", line)
+        if cur is None or not m:
+            continue
+        instrs, root = comps[cur]
+        operands = [re.sub(r"/\*.*?\*/", "", o).strip().lstrip("%")
+                    for o in m.group(5).split(",") if o.strip()]
+        instrs[m.group(2)] = (m.group(3), m.group(4), operands, m.group(6))
+        comps[cur] = (instrs, m.group(2) if m.group(1) else root)
+    return comps
+
+
+def _producer(comps: dict, comp: str, name: str, index=None) -> str:
+    """The op that computes ``name`` (element ``index`` of a tuple), seen
+    through tuples, fusions, while loops and bitcasts (views of the same
+    bytes)."""
+    instrs, _ = comps[comp]
+    _type, op, operands, attrs = instrs[name]
+    if op == "bitcast":
+        return _producer(comps, comp, operands[0], index)
+    if op == "get-tuple-element":
+        return _producer(comps, comp, operands[0],
+                         int(re.search(r"index=(\d+)", attrs).group(1)))
+    if op == "tuple":
+        return _producer(comps, comp, operands[index])
+    if op in ("fusion", "while"):
+        sub = re.search(r"(?:calls|body)=%([^,\s]+)", attrs).group(1)
+        return _producer(comps, sub, comps[sub][1], index)
+    return op
+
+
+def _bs_scan_stage(which: str):
+    """The Black–Scholes stage as the scan executor drives it at 2^27 on
+    ``TPU_V5E``: the stage, its driver's arguments and the batch it picks
+    (the §5.2 estimate, or the largest tuner candidate)."""
+    n = 1 << 27
+    ex = get_executor("scan")
+    plan_cache.clear()
+    with mozart.session(executor="scan", chip=hardware.TPU_V5E) as ctx:
+        outs = w.black_scholes(**w.black_scholes_data(4096))
+        (stage,), _entry = lookup_or_plan(ctx.graph.pending(), ctx.graph, ctx)
+        concrete = {k: si.value for k, si in stage.inputs.items()}
+        tile = executor._stage_tile(stage, concrete)
+        est = ex.estimate_batch(stage, concrete, ctx, 4096)
+        batch = {"estimate": executor._aligned(min(est, n), n, tile),
+                 "tuner_max": max(ex.tuning_candidates(stage, concrete, ctx,
+                                                       est, n))}[which]
+    del outs
+    return stage, concrete, n, batch, tile
+
+
+@pytest.mark.parametrize("which", ["estimate", "tuner_max"])
+def test_scan_driver_is_flat_at_real_size(one_chip, which):
+    """Chunks are read in place from the flat inputs and written into flat
+    outputs: the inputs keep their ``{0:T(1024)}`` layout, every chunk is
+    whole tiles (``f32[batch]{0:T(1024)}``, or the same bytes as rows of
+    128 lanes, ``f32[batch/128,128]{1,0:T(8,128)}``), never one-row
+    ``T(1,128)`` slab rows; nothing input-sized is copied, and every
+    whole-size output comes out of a ``dynamic-update-slice``."""
+    stage, concrete, n, batch, tile = _bs_scan_stage(which)
+    assert tile == 1024 and batch % tile == 0 and n // batch > 1
+    split_keys = [k for k, si in stage.inputs.items()
+                  if si.split_type.splittable]
+    split_axes = {stage.ckey(k): 0 for k in split_keys}
+    esc = tuple(stage.escape_positions())
+    out_axes = {stage.pos[nid]: split_axis_of(stage.out_types[nid])
+                for nid in stage.escaping}
+    driver = executor._build_scan_driver(stage, esc, split_axes, out_axes,
+                                         batch)
+    args = {ck: jax.ShapeDtypeStruct((n,), jnp.float32, sharding=one_chip)
+            for ck in split_axes}
+    bcast = {stage.ckey(k): jax.ShapeDtypeStruct(
+                 (), jnp.result_type(concrete[k]), sharding=one_chip)
+             for k in stage.inputs if k not in split_keys}
+    compiled = driver.lower(args, bcast).compile()
+    hlo = compiled.as_text()
+
+    whole = (f"f32[{n}]", f"f32[{n // 128},128]")
+    chunk = (rf"f32\[{batch}\]\{{0:T\(1024\)",
+             rf"f32\[{batch // 128},128\]\{{1,0:T\(8,128\)")
+    slices = [l for l in hlo.splitlines() if " dynamic-slice(" in l]
+    assert slices
+    for line in slices:
+        assert any(re.search("= " + c, line) for c in chunk), line
+    assert "T(1,128)" not in hlo
+    params = [t for t, op, _o, _a in _computations(hlo)["ENTRY"][0].values()
+              if op == "parameter" and t.startswith("f32[") and "[]" not in t]
+    assert params and all(t == f"f32[{n}]{{0:T(1024)}}" for t in params)
+    copies = [l for l in hlo.splitlines() if " copy(" in l
+              and any(w in l.split(" copy(")[0] for w in whole)]
+    assert copies == []
+    assert compiled.memory_analysis().temp_size_in_bytes < n * 4
+
+    comps = _computations(hlo)
+    entry, root = comps["ENTRY"]
+    outputs = entry[root][2]
+    assert len(outputs) == len(esc)
+    for i in range(len(outputs)):
+        assert _producer(comps, "ENTRY", root, i) == "dynamic-update-slice"
+    loop = next(a for _t, op, _o, a in entry.values() if op == "while")
+    body = re.search(r"body=%([^,\s]+)", loop).group(1)
+    body_root = comps[body][0][comps[body][1]]
+    written = [i for i, t in enumerate(re.findall(r"\w+\[[^\]]*\]",
+                                                  body_root[0]))
+               if t in whole and _producer(comps, body, comps[body][1], i)
+               == "dynamic-update-slice"]
+    assert len(written) == len(esc)
+
+
+@pytest.mark.parametrize("shape,dtype,axis", [
+    ((1 << 20,), jnp.float32, 0), ((1 << 20,), jnp.bfloat16, 0),
+    ((4096, 256), jnp.float32, 0), ((4096, 256), jnp.float32, 1),
+    ((4096, 256), jnp.bfloat16, 0), ((4096, 256), jnp.bfloat16, 1),
+    ((64, 64, 256), jnp.float32, 0)])
+def test_split_tile_matches_compiled_layout(one_chip, shape, dtype, axis):
+    """``split_tile`` is the compiler's own tile along the split axis."""
+    compiled = jax.jit(lambda x: jnp.exp(x) * 2).lower(
+        jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)).compile()
+    entry, _root = _computations(compiled.as_text())["ENTRY"]
+    layout = next(t for t, op, _o, a in entry.values()
+                  if op == "parameter" and ", " not in t)
+    tiles = [int(x) for x in re.search(r":T\(([0-9,]+)\)", layout)
+             .group(1).split(",")]
+    along = dict(zip(range(len(shape) - len(tiles), len(shape)), tiles))
+    assert executor.split_tile(shape, axis) == along.get(axis, 1), layout
