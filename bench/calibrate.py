@@ -27,12 +27,12 @@ sys.path.insert(0, str(Path(__file__).resolve().parents[1]))
 from bench import run as bench_run  # noqa: E402
 
 
-def readings(cell, call, seeds, calls):
+def readings(cell, call, seeds, calls, mesh=None):
     import gc
     out = []
     for seed in seeds:
         batches = bench_run.make_batches(cell.reference_module(),
-                                         cell.traffic, seed)
+                                         cell.traffic, seed, mesh)
         loop = bench_run.Loop(call, batches, bench_run.Reservoir(
             int(cell.traffic["check_calls"]), seed))
         loop.run(calls=calls)
@@ -73,9 +73,11 @@ def main(argv=None) -> int:
 
     cell = load_cell(args.workload)
     dev = jax.devices()[0]
-    if dev.platform != "tpu":
-        print("calibrate: JAX found no TPU", file=sys.stderr)
+    if dev.platform != "tpu" or len(jax.devices()) < cell.chips:
+        print("calibrate: JAX found no TPU, or fewer chips than the cell "
+              "asks for", file=sys.stderr)
         return 2
+    mesh = bench_run.make_mesh(cell.chips)
     traffic = cell.traffic
     calls = min(2 * int(traffic["batches"]), 256)
     seeds = [args.first_seed + 7919 * i for i in range(args.seeds)]
@@ -84,9 +86,10 @@ def main(argv=None) -> int:
     summary = {"workload": cell.name, "calls_per_seed": calls}
 
     batches = bench_run.make_batches(cell.reference_module(), traffic,
-                                     seeds[0])
+                                     seeds[0], mesh)
     t0 = time.perf_counter()
-    call, p = bench_run.build_program(cell, batches, hardware.chip_for(dev))
+    call, p = bench_run.build_program(cell, batches, hardware.chip_for(dev),
+                                      mesh=mesh)
     print(f"[calibrate] {p.describe()} (built in "
           f"{time.perf_counter() - t0:.1f} s)", flush=True)
     if args.baselines:
@@ -105,12 +108,12 @@ def main(argv=None) -> int:
         print(f"[calibrate] baselines (median ms a call) "
               f"{json.dumps(summary['baselines_ms'])}", flush=True)
     del batches
-    summary["program"] = readings(cell, call, seeds, calls)
+    summary["program"] = readings(cell, call, seeds, calls, mesh)
     del call, p
     from repro.core import plan_cache
     plan_cache.clear()
     ctl, _ = bench_run.build_program(cell, [], None, control=True)
-    summary["control"] = readings(cell, ctl, ctl_seeds, calls)
+    summary["control"] = readings(cell, ctl, ctl_seeds, calls, mesh)
     print(json.dumps(summary), flush=True)
     return 0
 

@@ -10,6 +10,13 @@ reader.  Each is found by its name alone:
     metrics/<metric>.py              ``read(reading) -> float | None``
 
 So a new cell, configuration or metric needs new files and no edit here.
+
+A cell's ``chips`` is the number of chips it runs on.  Above 1, ``run.py``
+builds a ``data`` mesh over exactly that many chips and hands it to the
+pipeline; the batches are made on that mesh, split along their first axis;
+the trace is read on each of those chips' device planes, busy being the
+union over them, op totals per chip, and the least time for the work
+counted against every chip's peaks.
 """
 
 from __future__ import annotations

@@ -21,6 +21,16 @@ metrics are read from it (``reduction.py``, ``metrics/``).  ``--control``
 puts the configuration's reference, computed in its ``control_dtype``, in
 the pipeline's place: that run must come out not correct.
 
+A cell whose ``chips`` is above 1 runs on a mesh of exactly that many
+chips, the first of ``jax.devices()``, with the one axis ``data``: the
+pipeline is built with ``mesh=`` (a one-chip cell passes none), and each
+batch is made on that mesh, every leaf of rank 1 or more split along its
+first axis over the chips and every scalar replicated, so no chip holds a
+whole batch.  Its trace is read on the device plane of each of those chips:
+busy is the union over the chips, the breakdown's op totals are per chip,
+the least time for the work counts every chip's peaks, and
+``memory_peak_bytes`` is the fullest chip's.
+
 The last line of stdout is the result; the numbers compared, each beside
 its limit, are the last lines of stderr.  Without a TPU, or with fewer
 chips than the cell asks for, it exits 2 and prints no result.
@@ -83,12 +93,27 @@ def seed_key(seed: int):
     return jax.random.wrap_key_data(jnp.asarray(words, jnp.uint32))
 
 
-def make_batches(ref, traffic: dict, seed: int) -> list:
-    """The traffic's distinct input batches, made on the device."""
+def make_mesh(chips: int):
+    """The ``data`` mesh over the first ``chips`` devices; None for one chip."""
     import jax
+    if chips == 1:
+        return None
+    return jax.make_mesh((chips,), ("data",), devices=jax.devices()[:chips])
+
+
+def make_batches(ref, traffic: dict, seed: int, mesh=None) -> list:
+    """The traffic's distinct input batches, made on the device; on a
+    ``mesh``, made there already split over its ``data`` axis."""
+    import jax
+    from jax.sharding import NamedSharding, PartitionSpec as P
     key = seed_key(seed)
-    gen = jax.jit(ref.make_batch, static_argnums=1)
     n = int(traffic["elements_per_call"])
+    shardings = None
+    if mesh is not None:
+        shardings = jax.tree.map(
+            lambda x: NamedSharding(mesh, P("data") if x.ndim else P()),
+            jax.eval_shape(lambda k: ref.make_batch(k, n), key))
+    gen = jax.jit(ref.make_batch, static_argnums=1, out_shardings=shardings)
     batches = [gen(jax.random.fold_in(key, i), n)
                for i in range(int(traffic["batches"]))]
     return jax.block_until_ready(batches)
@@ -101,14 +126,17 @@ def reference_fn(ref, dtype_name: str):
                                      dtype=jnp.dtype(dtype_name)))
 
 
-def build_program(cell, batches: list, chip, control: bool = False):
+def build_program(cell, batches: list, chip, control: bool = False,
+                  mesh=None):
     """``(call, pipeline)``: ``call(batch) -> (outputs, stats delta)``."""
     if control:
         f = reference_fn(cell.reference_module(), cell.config["control_dtype"])
         return (lambda b: (f(**b), {})), None
     from repro.core import mozart
+    on_mesh = {} if mesh is None else {"mesh": mesh}
     p = mozart.pipeline(cell.workload_module().workload,
-                        executor=cell.traffic["executor"], chip=chip)
+                        executor=cell.traffic["executor"], chip=chip,
+                        **on_mesh)
     p.lower(**batches[0]).compile()
     return (lambda b: p.call_with_stats(**b)), p
 
@@ -335,9 +363,11 @@ def run_cell(cell, seed: int, seconds: float, trace: bool = False, *,
     peaks = peaks_for(dev.device_kind) if require_tpu else None
     events = CompileEvents(jax)
     ref = cell.reference_module()
-    batches = make_batches(ref, cell.traffic, seed)
+    mesh = make_mesh(cell.chips)
+    batches = make_batches(ref, cell.traffic, seed, mesh)
     marks.append(("data", time.perf_counter()))
-    call, p = build_program(cell, batches, hardware.chip_for(dev), control)
+    call, p = build_program(cell, batches, hardware.chip_for(dev), control,
+                            mesh)
     marks.append(("lower and compile", time.perf_counter()))
     if wrap_call is not None:
         call = wrap_call(call)
@@ -383,12 +413,14 @@ def run_cell(cell, seed: int, seconds: float, trace: bool = False, *,
     if loop.errors:
         log(f"[bench] {loop.raised} calls raised; first: {loop.errors[0]}")
 
-    stats = dev.memory_stats() or {}
-    memory_peak = int(stats.get("peak_bytes_in_use", 0))
+    chip_stats = [d.memory_stats() or {} for d in devices[:cell.chips]]
+    peaks_in_use = [int(st.get("peak_bytes_in_use", 0)) for st in chip_stats]
+    memory_peak = max(peaks_in_use)
+    stats = chip_stats[peaks_in_use.index(memory_peak)]
     log(f"[bench] memory: " + ", ".join(
         f"{k} {stats[k]}" for k in ("bytes_in_use", "peak_bytes_in_use",
                                    "largest_free_block_bytes", "bytes_limit")
-        if k in stats))
+        if k in stats) + f"; peak_bytes_in_use per chip {peaks_in_use}")
     kept = loop.keep.kept
     attempted, failed, raised = loop.i, loop.failed, loop.raised
     flops, nbytes = compulsory_work(cell, batches[0], ref)
@@ -410,7 +442,7 @@ def run_cell(cell, seed: int, seconds: float, trace: bool = False, *,
     device = {"platform": dev.platform, "kind": dev.device_kind,
               "count": len(devices), "memory_peak_bytes": memory_peak}
     if trace:
-        r = reduction.reduce(reduction.load(str(trace_path)))
+        r = reduction.reduce(reduction.load(str(trace_path), cell.chips))
         metrics = {}
         if r is not None:
             calls_traced = r.calls
@@ -423,12 +455,15 @@ def run_cell(cell, seed: int, seconds: float, trace: bool = False, *,
                 v = metric_reader(m["name"], cell.bench)(r)
                 if v is not None:
                     metrics[m["name"]] = {"value": v, "unit": m["unit"]}
-            device.update(busy_s=r.busy_s, window_s=r.window_s)
+            # busy_s of each chip, averaged; idle_share reads the union.
+            device.update(busy_s=sum(r.chip_busy_s) / r.chips,
+                          window_s=r.window_s)
             result["breakdown"] = {
                 "device_ops": [list(o) for o in r.op_totals],
                 "idle_gaps": [list(g) for g in r.gaps]}
             log(f"[bench] trace: {r.calls} calls, window {r.window_s!r} s, "
-                f"busy {r.busy_s!r} s, idle in calls {r.idle_in_calls_s!r} s")
+                f"busy {r.busy_s!r} s, idle in calls {r.idle_in_calls_s!r} s, "
+                f"busy per chip {list(r.chip_busy_s)} s")
         result["metrics"] = metrics
     else:
         result["metrics"] = {m["name"]: {"value": e2e[m["name"]],

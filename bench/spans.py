@@ -12,15 +12,16 @@ by one thread.  From those inside the traced window (``reduction.py``):
 - each span's self time: its duration less what its child spans cover;
 - the sums of each span's numeric attributes (``mozart.stage`` carries
   its ``layout_bytes``);
-- the device-idle time inside ``bench.call``, split by the innermost
-  program span open at each idle instant, and the rest, where none is
-  open ("unattributed").
+- the device-idle time inside ``bench.call`` (no chip of the cell busy),
+  split by the innermost program span open at each idle instant, and the
+  rest, where none is open ("unattributed").
 
 A metric reader is handed only the window's ``reduction.Reading``;
 ``of_reading`` finds the trace it was reduced from among those ``run.py``
-writes (``out/trace/<cell>/``), by reducing each again until one reads the
-same, and prints the spans' per-call summary once.  A trace with no
-program span (a program without them) gives ``None``.
+writes (``out/trace/<cell>/``), by reducing each again, over as many
+chips' planes as the reading has, until one reads the same, and prints
+the spans' per-call summary once.  A trace with no program span (a
+program without them) gives ``None``.
 """
 
 from __future__ import annotations
@@ -183,16 +184,14 @@ def _intersect(a, b) -> list:
 def in_window(ev, program) -> Totals | None:
     """``totals`` of the program spans inside the window ``reduction.reduce``
     reads from ``ev`` (first ``bench.call`` start to last ``bench.wait``
-    end), with the device idle inside ``bench.call`` as it computes it."""
-    calls = sorted((s, e) for n, s, e in ev.spans if n == reduction.CALL)
-    waits = sorted((s, e) for n, s, e in ev.spans if n == reduction.WAIT)
-    if not calls:
+    end), with the device idle inside ``bench.call`` as it computes it: idle
+    while no chip of the cell runs an operation."""
+    if (w := reduction.window(ev)) is None:
         return None
-    lo = calls[0][0]
-    hi = max([e for _, e in waits] + [calls[-1][1]])
-    busy = reduction.merge(reduction._clip(
-        [(s, e) for _, s, e in ev.ops if e > lo and s < hi], lo, hi))
+    lo, hi = w
+    busy = reduction.busy([op for ops, _ in ev.planes() for op in ops], lo, hi)
     idle = reduction._complement(busy, lo, hi)
+    calls = [(s, e) for n, s, e in ev.spans if n == reduction.CALL]
     return totals([sp for sp in program if sp[1] >= lo and sp[2] <= hi],
                   _intersect(idle, reduction.merge(calls)))
 
@@ -245,7 +244,7 @@ def of_reading(r) -> Totals | None:
                    key=lambda p: p.stat().st_mtime, reverse=True)
     for path in paths:
         profile = ProfileData.from_file(str(path))
-        ev = reduction.events_from_profile(profile)
+        ev = reduction.events_from_profile(profile, r.chips)
         again = reduction.reduce(ev)
         if again is not None and _same(again, r):
             found = in_window(ev, from_profile(profile))

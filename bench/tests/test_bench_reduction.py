@@ -6,6 +6,7 @@ written by hand (``fixtures/two_calls.pbtxt``, times in ns):
             mozart_split_pipeline [790,900] (past the window's end)
 """
 
+import dataclasses
 from pathlib import Path
 
 import pytest
@@ -121,3 +122,85 @@ def test_recorded_trace_adds_up():
                    for s, e in idle for cs, ce in calls)
     assert r.idle_in_calls_s == pytest.approx(pairwise * 1e-9)
     assert sum(t for _, t in r.gaps) == pytest.approx(r.window_s - r.busy_s)
+
+
+# What ``reduce`` gave for the recorded one-chip traces before it read a
+# mesh's planes: a one-chip reading must stay the same to the bit.
+ONE_CHIP = {
+    "two_calls.pbtxt": dict(
+        window_s=8.000000000000001e-07, busy_s=4.4e-07, calls=2,
+        idle_in_calls_s=1.0000000000000001e-07,
+        op_totals=[("jit_driver/fusion.1", 2.8e-07),
+                   ("jit_driver/copy.2", 1.8000000000000002e-07),
+                   ("jit_driver/mozart_split_pipeline", 1e-08)],
+        gaps=[("loop", 2.2e-07), ("bench.wait", 9.000000000000001e-08),
+              ("bench.call", 5.0000000000000004e-08)],
+        flops=0.0, bytes=0.0, peak_flops_per_s=0.0, peak_bytes_per_s=0.0,
+        call_median_s=0.0),
+    "dc_two_calls.pbtxt": dict(
+        window_s=0.30093067700000004, busy_s=0.013249603, calls=2,
+        idle_in_calls_s=0.287575214,
+        op_totals=[("jit_slice/slice.1", 0.006707186),
+                   ("jit_fused_driver/fusion.1", 0.006542407),
+                   ("jit_fused_driver/copy-start", 5e-09),
+                   ("jit_fused_driver/copy-done", 5e-09)],
+        gaps=[("bench.call", 0.041429277), ("bench.call", 0.039490417),
+              ("bench.call", 0.002287584), ("bench.call", 0.001670416),
+              ("bench.call", 0.001486991),
+              ("bench.call", 0.0014652620000000002),
+              ("bench.call", 0.001427389),
+              ("bench.call", 0.0014212950000000002),
+              ("bench.call", 0.001410957), ("bench.call", 0.001367327)],
+        flops=0.0, bytes=0.0, peak_flops_per_s=0.0, peak_bytes_per_s=0.0,
+        call_median_s=0.0),
+}
+
+
+@pytest.mark.parametrize("fixture", sorted(ONE_CHIP))
+def test_one_chip_fixture_reads_to_the_bit_as_before(fixture):
+    profile = ProfileData.from_text_proto((FIXTURES / fixture).read_text())
+    r = reduction.reduce(reduction.events_from_profile(profile))
+    got = dataclasses.asdict(r)
+    assert {k: got[k] for k in ONE_CHIP[fixture]} == ONE_CHIP[fixture]
+    assert r.chips == 1 and r.chip_busy_s == (r.busy_s,)
+    r.flops, r.bytes = 3.0e12, 2 * 1.07e9
+    r.peak_flops_per_s, r.peak_bytes_per_s = 197e12, 819e9
+    assert r.min_time_s() == max(3.0e12 / 197e12, 2 * 1.07e9 / 819e9)
+
+
+def four_chips(durations, start=100):
+    """One call over [0, 1000] ns; chip ``i`` runs one ``fusion.1`` of
+    ``durations[i]`` ns from ``start`` on."""
+    planes = [([("%fusion.1 = f32[8] fusion(...)", start, start + d)],
+               [("jit_driver(1)", 0, 1000)]) for d in durations]
+    (ops, modules), *others = planes
+    return reduction.Events(
+        ops=ops, modules=modules, others=others,
+        spans=[("bench.call", 0, 900), ("bench.wait", 900, 1000)])
+
+
+def test_per_chip_peaks_bound_a_mesh_at_100_percent():
+    """Each of four chips moves its quarter of a call's bytes at one chip's
+    peak over the same 800 ns: the mesh is at its roofline."""
+    r = reduction.reduce(four_chips([800] * 4))
+    assert r.chips == 4
+    assert r.busy_s == pytest.approx(800e-9)
+    r.peak_flops_per_s, r.peak_bytes_per_s = 197e12, 819e9
+    r.bytes = 4 * 819e9 * 800e-9
+    assert metric_reader("device_roofline")(r) == pytest.approx(100.0)
+    assert metric_reader("call_mfu")(r) == pytest.approx(80.0)
+
+
+def test_mesh_busy_is_the_union_over_chips():
+    """A chip busy twice as long as the others sets the mesh's busy time;
+    each op's total is per chip."""
+    r = reduction.reduce(four_chips([300, 300, 300, 600]))
+    assert r.busy_s == pytest.approx(600e-9)
+    assert r.chip_busy_s == pytest.approx((300e-9, 300e-9, 300e-9, 600e-9))
+    assert dict(r.op_totals) == {
+        "jit_driver/fusion.1": pytest.approx((3 * 300 + 600) / 4 * 1e-9)}
+    # idle [0,100] and [700,1000], of which [700,900] lies in the call
+    assert r.idle_in_calls_s == pytest.approx(300e-9)
+    assert metric_reader("idle_share")(r) == pytest.approx(40.0)
+    assert sorted(r.gaps) == [("bench.call", pytest.approx(100e-9)),
+                              ("bench.call", pytest.approx(300e-9))]
