@@ -324,21 +324,45 @@ def _aligned(batch: int, n: int, tile: int) -> int:
     return batch if batch >= n or batch < tile else batch // tile * tile
 
 
-def _build_scan_driver(stage: Stage, esc: tuple[int, ...],
-                       split_axes: dict[tuple, int],
-                       out_axes: dict[int, int | None], batch: int,
-                       donate: tuple = ()) -> Callable:
-    """One compiled program for the whole stage: a ``fori_loop`` over the
-    main chunks and one more call of the chain on the ragged tail.
+def tiled_batch(batch: int, stage: Stage, concrete: dict[tuple, Any], ctx,
+                n: int) -> tuple[int, int]:
+    """``(batch, tile)`` for a chunk loop over ``n`` elements: the batch
+    rounded down to the stage's layout tile (``split_tile``) so every chunk
+    starts on a tile boundary, and that tile (1 where no chunk is cut on
+    one).  An explicit ``batch_elements`` is kept as given."""
+    if batch_is_explicit(ctx):
+        return batch, 1
+    tile = _stage_tile(stage, concrete)
+    aligned = _aligned(batch, n, tile)
+    return aligned, (tile if aligned < n and tile <= batch else 1)
 
-    Split inputs arrive flat, in their own shapes; chunk ``i`` is a
-    ``dynamic_slice`` of each at ``i * batch`` (a 1-D value sliced as rows
-    of 128 lanes), which XLA fuses into the chain.  Split outputs are allocated whole in the loop carry and each
-    chunk's result goes in with an in-place ``dynamic_update_slice``, so
-    the driver's outputs are the merged values; a reduction output (the
-    one kind without a split axis) folds into the carry in chunk order
-    (``ReduceSplit.merge``).  ``n`` is read from the inputs' shapes, so one
-    pinned driver serves every length at this batch."""
+
+def tiled_candidates(stage: Stage, concrete: dict[tuple, Any], est: int,
+                     n: int) -> list[int]:
+    """The tuner's chunk sizes for a loop over ``n`` elements, each rounded
+    down to the stage's layout tile."""
+    tile = _stage_tile(stage, concrete)
+    return sorted({_aligned(c, n, tile) for c in candidate_batches(est, n)})
+
+
+def chunk_loop(stage: Stage, esc: tuple[int, ...],
+               split_axes: dict[tuple, int],
+               out_axes: dict[int, int | None], batch: int,
+               donate: tuple = ()) -> Callable:
+    """The stage's chunk loop, unjitted: ``drive(split_vals, bcast_env)``
+    returns ``{output position: whole output}``.  The ``scan`` driver jits
+    it over the flat values; the ``sharded`` driver runs it on each shard.
+
+    A ``fori_loop`` runs the chain on the main chunks and one more call of
+    it runs on the ragged tail, in the same program.  Split inputs arrive
+    flat, in their own shapes; chunk ``i`` is a ``dynamic_slice`` of each
+    at ``i * batch`` (a 1-D value sliced as rows of 128 lanes), which XLA
+    fuses into the chain.  Split outputs are allocated whole in the loop
+    carry and each chunk's result goes in with an in-place
+    ``dynamic_update_slice``, so the loop's outputs are the merged values;
+    a reduction output (the one kind without a split axis) folds into the
+    carry in chunk order (``ReduceSplit.merge``).  ``n`` is read from the
+    inputs' shapes, so one loop serves every length at this batch."""
     plan = chain_plan(stage)
     reduce_types = {stage.pos[nid]: t for nid, t in stage.out_types.items()
                     if nid in stage.escaping and isinstance(t, st.ReduceSplit)}
@@ -446,10 +470,21 @@ def _build_scan_driver(stage: Stage, esc: tuple[int, ...],
             bufs = put(bufs, tail, n_chunks, n_main)
         return bufs
 
+    return drive
+
+
+def _build_scan_driver(stage: Stage, esc: tuple[int, ...],
+                       split_axes: dict[tuple, int],
+                       out_axes: dict[int, int | None], batch: int,
+                       donate: tuple = ()) -> Callable:
+    """One compiled program for the whole stage: ``chunk_loop`` over the
+    flat values."""
+    drive = chunk_loop(stage, esc, split_axes, out_axes, batch, donate)
+
     if donate:
         # Dead split inputs arrive as a separate donated argument; the
-        # pairing above lets XLA write an output into a donated input's
-        # buffer as it goes.
+        # pairing in ``chunk_loop`` lets XLA write an output into a donated
+        # input's buffer as it goes.
         def mozart_scan_driver_donate(donated: dict, split_vals: dict,
                                       bcast_env: dict):
             return drive({**split_vals, **donated}, bcast_env)
@@ -491,15 +526,12 @@ class ScanExecutor(StageExecutor):
 
     tunable = True
     stream_capable = True
+    one_device = True
 
     def _batch_and_tile(self, stage: Stage, concrete: dict[tuple, Any], ctx,
                         n: int) -> tuple[int, int]:
-        batch = super().choose_batch(stage, concrete, ctx, n)
-        if batch_is_explicit(ctx):
-            return batch, 1
-        tile = _stage_tile(stage, concrete)
-        aligned = _aligned(batch, n, tile)
-        return aligned, (tile if aligned < n and tile <= batch else 1)
+        return tiled_batch(super().choose_batch(stage, concrete, ctx, n),
+                           stage, concrete, ctx, n)
 
     def choose_batch(self, stage: Stage, concrete: dict[tuple, Any], ctx,
                      n: int) -> int:
@@ -507,9 +539,7 @@ class ScanExecutor(StageExecutor):
 
     def tuning_candidates(self, stage: Stage, concrete: dict[tuple, Any], ctx,
                           est: int, n: int) -> list[int]:
-        tile = _stage_tile(stage, concrete)
-        return sorted({_aligned(c, n, tile)
-                       for c in candidate_batches(est, n)})
+        return tiled_candidates(stage, concrete, est, n)
 
     def _fallback(self, name: str, stage: Stage, concrete: dict[tuple, Any],
                   ctx) -> None:

@@ -107,6 +107,7 @@ class PallasExecutor(StageExecutor):
 
     tunable = True
     stream_capable = True
+    one_device = True
 
     def execute(self, stage: Stage, concrete: dict[tuple, Any], ctx) -> None:
         if not try_execute_stage_pallas(stage, concrete, ctx, self):

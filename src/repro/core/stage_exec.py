@@ -34,6 +34,7 @@ from typing import Any, Callable
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import AxisType, NamedSharding, SingleDeviceSharding
 
 from repro import hardware
 from repro.core import resilience
@@ -847,7 +848,8 @@ def adapt_stream(v: "ChunkStream", consumer: st.SplitType) -> "ChunkStream | Non
 
 def resolve_stage_inputs(stage: Stage, graph: DataflowGraph, ctx,
                          streams_ok: bool, tally: bool = True,
-                         shard_ok: bool = False) -> dict[tuple, Any]:
+                         shard_ok: bool = False,
+                         one_device: bool = False) -> dict[tuple, Any]:
     """Resolve stage inputs, ingesting producer ChunkStreams where allowed.
 
     An input keeps its stream form only when (a) the executor can iterate a
@@ -862,6 +864,9 @@ def resolve_stage_inputs(stage: Stage, graph: DataflowGraph, ctx,
     single-device chunk loop must not iterate them; materializing instead
     lets XLA reshard (counted ``interior:gather``).  Anything else is
     materialized — correct by construction, merely the old cost.
+    ``one_device`` (an executor whose program refuses a mesh layout) also
+    gathers each input laid out over a mesh's explicit axes onto one device
+    of that mesh (``off_mesh``).
     ``tally=False`` skips the ingest/materialize stats (scoring-only
     resolves, e.g. ``AutoExecutor``, whose delegate re-resolves and counts)."""
     if tally:
@@ -872,6 +877,7 @@ def resolve_stage_inputs(stage: Stage, graph: DataflowGraph, ctx,
     concrete: dict[tuple, Any] = {}
     for i, (key, si) in enumerate(stage.inputs.items()):
         v = graph.resolve(si.value)
+        gathered = False        # a sharded stream's materialize counts it
         if isinstance(v, ChunkStream):
             reason = _stream_fallback_reason(v, si, i, ho, streams_ok,
                                              shard_ok)
@@ -905,11 +911,33 @@ def resolve_stage_inputs(stage: Stage, graph: DataflowGraph, ctx,
                         0, kind="fallback",
                         where=f"[MZ203] stage {stage.id} input "
                               f"{stage.ckey(key)}: {reason}")
+                gathered = v.sharded is not None
                 v = v.materialize()
                 if tally:
                     ctx.stats["stream_materialized"] += 1
+        if one_device:
+            v = off_mesh(v, counted=gathered,
+                         where=f"stage {stage.id} input {stage.ckey(key)}")
         concrete[key] = v
     return concrete
+
+
+def off_mesh(v: Any, counted: bool = False, where: str = "") -> Any:
+    """``v`` gathered onto the first device of its mesh where it is laid out
+    over the mesh's explicit axes, else ``v``.  Such a layout is part of the
+    value's type and follows it into the program that reads it, where a
+    scan carry (no layout) and its chunk updates (laid out) disagree and a
+    Pallas kernel refuses it.  The copy is counted ``interior:gather``
+    unless ``counted`` (a sharded stream's materialize already did)."""
+    sharding = getattr(v, "sharding", None)
+    if not (isinstance(sharding, NamedSharding)
+            and len(sharding.device_set) > 1
+            and AxisType.Explicit in sharding.mesh.axis_types):
+        return v
+    if not counted:
+        note_materialized(_value_nbytes(v), kind="gather", where=where)
+    return jax.device_put(
+        v, SingleDeviceSharding(sharding.mesh.devices.flat[0]))
 
 
 def _stream_fallback_reason(v: "ChunkStream", si, i: int, ho,
@@ -1134,6 +1162,10 @@ class StageExecutor:
     #: different mesh devices).  Only the sharded executor places per-shard
     #: buffers; everything else materializes and lets XLA reshard.
     shard_capable: bool = False
+    #: whether ``execute``'s program refuses a value laid out over a mesh's
+    #: explicit axes (a scan carry, a Pallas kernel): such inputs are
+    #: gathered onto one device first (``off_mesh``, counted).
+    one_device: bool = False
 
     # -- template method ----------------------------------------------------
     def run(self, stage: Stage, graph: DataflowGraph, ctx) -> None:
@@ -1143,7 +1175,7 @@ class StageExecutor:
             with span("mozart.inputs"):
                 concrete = resolve_stage_inputs(
                     stage, graph, ctx, self.stream_capable,
-                    shard_ok=self.shard_capable)
+                    shard_ok=self.shard_capable, one_device=self.one_device)
             entry = getattr(ctx, "_plan_entry", None)
             if self._should_tune(stage, ctx, entry):
                 # Sampled tuning re-slices inputs at arbitrary offsets: a

@@ -24,6 +24,86 @@ def run_with_devices(body: str, n_devices: int = 8) -> str:
     return proc.stdout
 
 
+@pytest.mark.parametrize("batch", [1500, 3072], ids=["cols", "rows"])
+def test_sharded_matches_the_benchmark_references(batch):
+    """Differential: Black–Scholes and data cleaning (the benchmark's user
+    code) through ``executor="sharded"`` on a 4-device mesh, 2^12 elements
+    a shard, against the benchmark's plain references to their limits.
+    Each shard runs several chunks and a ragged tail (1500: sliced along
+    the axis; 3072: sliced as rows of 128 lanes); data cleaning's two sums
+    fold in the loop's carry and then add up across shards."""
+    out = run_with_devices(f"""
+        import warnings; warnings.filterwarnings("ignore")
+        import sys; sys.path.insert(0, {REPO!r})
+        from pathlib import Path
+        import jax
+        from bench.cells import load_module
+        from repro.core import mozart
+
+        configs = Path({REPO!r}) / "bench" / "configs"
+        mesh = jax.make_mesh((4,), ("data",))
+        n, batch = 1 << 14, {batch}
+        limits = {{"black-scholes-f32": ("price_abs_err", 1e-3),
+                   "data-cleaning-f32": ("sum_rel_err", 3e-5)}}
+        for name, (reading, limit) in limits.items():
+            user = load_module(configs / f"{{name}}.workload.py")
+            ref = load_module(configs / f"{{name}}.reference.py")
+            args = ref.make_batch(jax.random.key(7), n)
+            p = mozart.pipeline(user.workload, executor="sharded", mesh=mesh,
+                                batch_elements=batch)
+            outs, delta = p.call_with_stats(**args)
+            got = ref.compare(outs, ref.reference(**args))[reading]
+            assert got <= limit, (name, got)
+            chunks = -(-(n // 4) // batch)
+            assert delta["sharded_stages"] == 1, dict(delta)
+            assert delta["chunks"] == 4 * chunks, dict(delta)
+        print("OK")
+    """, n_devices=4)
+    assert "OK" in out
+
+
+def test_only_one_device_executors_gather_a_mesh_input():
+    """A user input laid out over an Explicit mesh axis: ``fused`` runs on
+    it as laid out, with no gather; ``scan`` and ``pallas``, whose programs
+    refuse the layout, gather it onto the first device of its own mesh,
+    counted once as ``interior:gather`` of its bytes."""
+    out = run_with_devices("""
+        import warnings; warnings.filterwarnings("ignore")
+        import numpy as np, jax, jax.numpy as jnp
+        from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
+        from repro.core import mozart, plan_cache, stage_exec
+        from repro.core import annotated_numpy as anp
+
+        mesh = jax.make_mesh((2,), ("data",), (AxisType.Explicit,),
+                             devices=jax.devices()[2:])
+        n = 4096
+        x = jax.device_put(jnp.linspace(0.0, 1.0, n, dtype=jnp.float32),
+                           NamedSharding(mesh, P("data")))
+        want = (np.linspace(0.0, 1.0, n, dtype=np.float32) + 1.0) * 0.5
+        put = []
+        real_put = jax.device_put
+        def spy(v, device=None, *a, **k):
+            put.append(device)
+            return real_put(v, device, *a, **k)
+        stage_exec.jax.device_put = spy
+        for executor, gathers in (("fused", 0), ("scan", 1), ("pallas", 1)):
+            plan_cache.clear()
+            put.clear()
+            with mozart.session(executor=executor,
+                                batch_elements=1024) as ctx:
+                got = np.asarray(anp.multiply(anp.add(x, 1.0), 0.5))
+            assert np.allclose(got, want, rtol=1e-6), executor
+            events = [e for e in ctx.counters.materialize_events()
+                      if e[0] == "interior:gather"]
+            assert len(events) == gathers, (executor, events)
+            assert all(e[2] == 4 * n for e in events), events
+            assert len(put) == gathers, (executor, put)
+            assert all(d.device_set == {jax.devices()[2]} for d in put), put
+        print("OK")
+    """, n_devices=4)
+    assert "OK" in out
+
+
 @pytest.mark.slow
 def test_sharded_elementwise_and_reduce():
     out = run_with_devices("""

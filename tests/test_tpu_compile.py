@@ -15,20 +15,24 @@ estimate and the largest block the tuner may try — and the decline rule
 elementwise op of the annotated NumPy library.  The ``scan`` driver for
 the Black–Scholes stage at 2^27, at the executor's batches, compiles to
 in-place chunk reads and writes on flat values, and ``split_tile`` agrees
-with the layouts the compiler assigns.
+with the layouts the compiler assigns.  The ``sharded`` driver for the same
+stage at 2^29 on the described four-chip mesh runs that chunk loop on each
+2^27 shard.
 """
 
 import os
 import re
+import time
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
-from jax.sharding import SingleDeviceSharding
+from jax.sharding import Mesh, NamedSharding, SingleDeviceSharding
 
 from benchmarks import workloads as w
 from repro import hardware
-from repro.core import executor, mozart, plan_cache
+from repro.core import executor, mozart, plan_cache, sharded
 from repro.core import split_types as st
 from repro.core import annotated_numpy as anp
 from repro.core.pallas_exec import _block_cap, _effective_block, _make_chain_fn
@@ -344,6 +348,50 @@ def test_scan_driver_is_flat_at_real_size(one_chip, which):
                if t in whole and _producer(comps, body, comps[body][1], i)
                == "dynamic-update-slice"]
     assert len(written) == len(esc)
+
+
+def test_sharded_driver_loops_each_shard_at_real_size(topo):
+    """Black–Scholes at 2^29 on a described four-chip ``data`` mesh, so
+    2^27 options a shard, at the executor's batch: each shard runs the
+    scan driver's chunk loop (a ``while``), takes ``f32[2^27]`` shards,
+    joins no chunk outputs with a ``concatenate`` and writes every
+    whole-shard output with a ``dynamic-update-slice``.  It compiles in
+    seconds, where the Python loop it replaced, unrolled over the shard's
+    chunks, took over a minute."""
+    stage, concrete, n_local, batch, _tile = _bs_scan_stage("estimate")
+    assert n_local // batch > 1 and n_local % batch
+    mesh = Mesh(np.array(topo.devices), ("data",))
+    axes = ("data",)
+    in_specs, out_specs = sharded._specs(stage, concrete, axes)
+    esc = tuple(stage.pos[nid] for nid in sorted(stage.escaping))
+    driver = sharded._build_sharded_driver(stage, mesh, axes, in_specs,
+                                           out_specs, esc, batch, True)
+    args = [jax.ShapeDtypeStruct(
+                (4 * n_local,) if si.split_type.splittable else (),
+                jnp.result_type(concrete[k]),
+                sharding=NamedSharding(mesh, spec))
+            for (k, si), spec in zip(stage.inputs.items(), in_specs)]
+    t0 = time.perf_counter()
+    compiled = driver.lower(*args).compile()
+    assert time.perf_counter() - t0 < 30
+    hlo = compiled.as_text()
+
+    chunk = (f"f32[{batch}]", f"f32[{batch // 128},128]")
+    joins = [l for l in hlo.splitlines()
+             if " concatenate(" in l and any(c in l for c in chunk)]
+    assert joins == []
+    comps = _computations(hlo)
+    entry, root = comps["ENTRY"]
+    assert any(op == "while" for _t, op, _o, _a in entry.values())
+    params = [t for t, op, _o, _a in entry.values()
+              if op == "parameter" and t.startswith("f32[") and "[]" not in t]
+    assert len(params) == sum(si.split_type.splittable
+                              for si in stage.inputs.values())
+    assert all(t == f"f32[{n_local}]{{0:T(1024)}}" for t in params)
+    outputs = entry[root][2]
+    assert len(outputs) == len(esc)
+    for i in range(len(outputs)):
+        assert _producer(comps, "ENTRY", root, i) == "dynamic-update-slice"
 
 
 @pytest.mark.parametrize("shape,dtype,axis", [

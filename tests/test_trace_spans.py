@@ -5,11 +5,13 @@ Each warm call of a one-stage pipeline opens ``mozart.call`` with its
 entry spans, and each stage ``mozart.stage`` with its children, nested as
 ``repro.core.trace`` documents; ``mozart.drive`` carries the call's chunk
 count and ``mozart.stage`` the executor that ran and the stage's
-``layout_bytes``.  ``layout_bytes`` is checked against a formula from
+``layout_bytes``; a sharded stage's ``mozart.drive`` also carries its
+shards and tile.  ``layout_bytes`` is checked against a formula from
 shapes.
 """
 
 import collections
+import dataclasses
 import glob
 import inspect
 import re
@@ -21,6 +23,7 @@ import numpy as np
 import pytest
 from jax.profiler import ProfileData
 
+from repro import hardware
 from repro.core import mozart, pallas_exec, trace
 from repro.core import annotated_numpy as anp
 from repro.core import split_types as st
@@ -126,6 +129,62 @@ def test_warm_calls_open_the_documented_spans(executor, tmp_path):
         assert by_name["mozart.evaluate"] == {"stages": 1}
         assert by_name["mozart.capture"] == {"nodes": 3}
     assert all(d["chunks"] == 5 for d in deltas)  # ceil(5000 / 1024)
+
+
+#: a chip whose fast memory takes 3276 elements of ``chain``'s 20 bytes, so
+#: a stage of 20000 runs 7 chunks of 3072, the estimate rounded to a tile.
+SMALL_VMEM = dataclasses.replace(hardware.TPU_V5E, vmem_bytes=64 * 1024,
+                                 mozart_c=1.0)
+
+
+def test_sharded_stage_opens_layout_drive_and_merge(tmp_path):
+    """On a one-device mesh, each warm call's sharded stage opens
+    ``mozart.layout``, ``mozart.drive`` and ``mozart.merge`` inside
+    ``mozart.stage``; ``mozart.drive`` carries the chunks of a shard, the
+    shards and the layout tile its batch was rounded to."""
+    n = 20000
+    args = _data(n)
+    p = mozart.pipeline(chain, executor="sharded", chip=SMALL_VMEM,
+                        mesh=jax.make_mesh((1,), ("data",)), autotune=False)
+    p.lower(*args).compile()
+    spans, deltas = _profiled_calls(p, args, 2, tmp_path)
+    per_call = _per_call(spans)
+    assert len(per_call) == 2
+    for (_call, inside), delta in zip(per_call, deltas):
+        for (name, _, _, _), parent in _with_parents(inside):
+            assert parent in PARENTS[name], (name, parent)
+        names = collections.Counter(s[0] for s in inside)
+        assert all(names[s] == 1 for s in ("mozart.stage", "mozart.layout",
+                                           "mozart.drive", "mozart.merge"))
+        by_name = {s[0]: s[3] for s in inside}
+        assert by_name["mozart.stage"] == {"stage": 0, "executor": "sharded",
+                                           "layout_bytes": 0}
+        assert by_name["mozart.drive"] == {"chunks": 7, "shards": 1,
+                                           "tile": 1024}
+        assert delta["chunks"] == 7 and delta["sharded_stages"] == 1
+    out, _ = p.call_with_stats(*args)
+    np.testing.assert_allclose(np.asarray(out),
+                               np.exp(2 * (np.asarray(args[0]) + 0.25)),
+                               rtol=1e-6)
+
+
+def test_sharded_ingest_counts_its_rechunk_as_layout_bytes():
+    """A chunk list from a fused stage, on a grid of 1000 against the
+    mesh's one shard of 4096, is regrouped onto the shard grid: one copy of
+    the whole value, counted in ``layout_bytes``."""
+    n = 4096
+    x = jnp.linspace(0.0, 1.0, n, dtype=jnp.float32)
+    with mozart.session(executor="fused", mesh=jax.make_mesh((1,), ("data",)),
+                        batch_elements=1000) as ctx:
+        cur = anp.multiply(anp.add(x, 1.0), 0.5)
+        mozart.evaluate()                   # fused producer: 5 chunks
+        assert ctx.stats.get("layout_bytes", 0) == 0
+        mozart.configure(executor="sharded")
+        got = np.asarray(anp.multiply(anp.add(cur, 1.0), 0.5))
+    np.testing.assert_allclose(got, (np.asarray(x) + 3.0) / 4.0, rtol=1e-6)
+    assert ctx.stats["handoff_rechunks"] == 1
+    assert ctx.stats["shard_ingests"] == 1
+    assert ctx.stats["layout_bytes"] == n * 4
 
 
 def test_fast_path_call_opens_no_capture_or_plan(tmp_path):
